@@ -161,6 +161,8 @@ def check_regressions(fresh: dict, committed: dict,
 
 
 def main() -> None:
+    from repro.core.runtime import use_jax_compile_cache
+    use_jax_compile_cache()
     from benchmarks import (compile_time, divergence_opt, interp_speed,
                             isa_ext, kernels_bench, robustness,
                             roofline_bench, serve_bench, sharedmem)

@@ -12,6 +12,7 @@ from repro.core.backends.asm import emit_asm
 from repro.core.backends.jax_backend import compile_jax
 from repro.core.frontends import opencl
 from repro.core.passes.pipeline import PassConfig, run_pipeline
+from repro.core.runtime import use_jax_compile_cache
 
 
 @opencl.kernel
@@ -30,6 +31,7 @@ def smooth(x: "ptr_f32 const", y: "ptr_f32", n: "i32 uniform"):
 
 
 def main() -> None:
+    use_jax_compile_cache()
     # 1. front-end + middle-end (uniformity, structurize, Algorithm 2)
     module = smooth.build(None)
     compiled = run_pipeline(module, "smooth",

@@ -28,12 +28,14 @@ import numpy as np
 import jax
 
 from repro.configs import get_config
+from repro.core.runtime import use_jax_compile_cache
 from repro.models import get_model
 from repro.models.blueprint import init_params
 from repro.serve.engine import EngineBusy, Request, ServeEngine
 
 
 def main() -> None:
+    use_jax_compile_cache()
     cfg = get_config("granite-3-2b", smoke=True)
     model = get_model(cfg)
     params = init_params(model.blueprint(), jax.random.PRNGKey(0))

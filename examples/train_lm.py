@@ -10,6 +10,7 @@ from dataclasses import replace
 import jax
 
 from repro.configs import get_config
+from repro.core.runtime import use_jax_compile_cache
 from repro.models import get_model
 from repro.models.blueprint import count_params
 from repro.train.data import DataConfig
@@ -19,6 +20,7 @@ from repro.train.train_step import StepConfig
 
 
 def main() -> None:
+    use_jax_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=16)

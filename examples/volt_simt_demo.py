@@ -9,11 +9,13 @@ import jax.numpy as jnp
 
 from repro.core import interp
 from repro.core.passes.pipeline import ABLATION_LADDER, run_pipeline
+from repro.core.runtime import use_jax_compile_cache
 from repro.core.simx import CycleModel
 from repro.volt_bench import BENCHES
 
 
 def main() -> None:
+    use_jax_compile_cache()
     model = CycleModel()
     for name in ("srad_flag", "transpose", "pathfinder"):
         b = BENCHES[name]

@@ -85,6 +85,13 @@ _SENT = 2**31 - 1
 ERR_OOB_STORE = 1
 ERR_UNIFORM = 2
 ERR_FUEL = 4
+ERR_OVERFLOW = 8     # an int32 line-request counter wrapped
+
+#: every counter of the trace is int32 (JAX runs without x64 on the
+#: TPU).  Instruction counts never exceed the fuel spent, so a budget
+#: below this bound keeps them exact; line-request counters can still
+#: outgrow it and raise ERR_OVERFLOW instead of wrapping silently
+FUEL_MAX = 2**30
 
 #: host-libm vs XLA transcendentals differ in ulps — certification
 #: would catch the mismatch anyway, but refusing up front keeps the
@@ -96,6 +103,10 @@ JAX_TELEMETRY = {
     "certified": 0,      # (kernel, shape) pairs newly certified "pass"
     "cert_runs": 0,      # differential certification launches
     "refusals": 0,       # licence/trace refusals (silent fallthrough)
+    "compile_errors": 0, # backend compile failures: demoted, reported
+                         # in the LaunchReport, retried next launch
+    "cert_errors": 0,    # exceptions of the jitted program while it
+                         # was being certified (kept with the verdict)
     "demotions": 0,      # certified launches that faulted -> grid
     "trace_cache_hits": 0,
     "routed_small": 0,   # certified but sent to the grid rung: the
@@ -163,24 +174,22 @@ class _TraceCtx:
     """Counter state threaded through one chunk trace.  All members are
     int32 device scalars with a FIXED structure (``cnt`` keys are the
     sorted op values reachable from the kernel), so the whole context
-    packs into a stable pytree for loop carries."""
+    packs into a stable pytree for loop carries — and across chunks:
+    each chunk starts from the previous chunk's packed counters, so the
+    launch totals never leave the device until the end.
+
+    Packed members: per-op counts, coalesced global line requests
+    (``mem``), coalesced shared-tile line requests (``shm``), load/store
+    instructions issued (``minst``), max two-sided IPDOM depth
+    (``maxd``), fuel spent, error bits."""
 
     __slots__ = ("cnt_keys", "cnt", "mem", "shm", "minst", "maxd",
                  "fuel", "err", "fuel_limit", "_live")
 
-    def __init__(self, cnt_keys: tuple, fuel_limit: int,
-                 fuel0) -> None:
-        z = jnp.int32(0)
+    def __init__(self, cnt_keys: tuple, fuel_limit: int, acc: tuple) -> None:
         self.cnt_keys = cnt_keys
-        self.cnt = {k: z for k in cnt_keys}
-        self.mem = z        # coalesced global line requests
-        self.shm = z        # coalesced shared-tile line requests
-        self.minst = z      # load/store instructions issued
-        self.maxd = z       # max two-sided IPDOM depth
-        self.fuel = jnp.asarray(fuel0, dtype=jnp.int32)
-        self.err = z
         self.fuel_limit = int(fuel_limit)
-        self._live = {}     # id(mask) -> (mask, active-row count)
+        self.unpack(acc)
 
     def live(self, mask):
         """Rows with any active lane — the oracle's per-warp
@@ -198,6 +207,16 @@ class _TraceCtx:
         self.cnt[opval] = self.cnt[opval] + n
         self.fuel = self.fuel + n
 
+    def add_lines(self, tile: bool, lines) -> None:
+        old = self.shm if tile else self.mem
+        new = old + lines
+        self.err = self.err | jnp.where(new < old, jnp.int32(ERR_OVERFLOW),
+                                        jnp.int32(0))
+        if tile:
+            self.shm = new
+        else:
+            self.mem = new
+
     def pack(self) -> tuple:
         return (tuple(self.cnt[k] for k in self.cnt_keys), self.mem,
                 self.shm, self.minst, self.maxd, self.fuel, self.err)
@@ -210,6 +229,12 @@ class _TraceCtx:
 
 
 _FUEL_IN_PACK = 5           # index of ``fuel`` in _TraceCtx.pack()
+
+
+def _zero_acc(n_ops: int) -> tuple:
+    """The packed counters a launch starts from."""
+    z = np.int32(0)
+    return ((z,) * n_ops,) + (z,) * 6
 
 
 class _State:
@@ -500,12 +525,10 @@ class _RowLowering:
         n = buf.shape[-1]
         clip = jnp.clip(ix, 0, n - 1)
         tc = self.tc
-        lines = self._count_lines(clip, st.mask)
+        tc.add_lines(nm in self.tiles, self._count_lines(clip, st.mask))
         if nm in self.tiles:
-            tc.shm = tc.shm + lines
             v = jnp.take_along_axis(buf, clip, axis=1)
         else:
-            tc.mem = tc.mem + lines
             v = buf[clip]
         tc.minst = tc.minst + tc.live(st.mask)
         self.env[id(i.result)] = v
@@ -526,12 +549,8 @@ class _RowLowering:
         tc.err = tc.err | jnp.where(bad, jnp.int32(ERR_OOB_STORE),
                                     jnp.int32(0))
         clip = jnp.clip(ix, 0, n - 1)
-        lines = self._count_lines(clip, m)
         tile = nm in self.tiles
-        if tile:
-            tc.shm = tc.shm + lines
-        else:
-            tc.mem = tc.mem + lines
+        tc.add_lines(tile, self._count_lines(clip, m))
         tc.minst = tc.minst + tc.live(m)
         wm = m & ~oob
         vv = v.astype(buf.dtype)
@@ -863,6 +882,8 @@ class _RowLowering:
 #: to the "exact" tier (backend level 0, every float op separately
 #: rounded) and re-certify — FMA-free kernels keep the optimized
 #: executable, accumulation kernels trade speed for bit-exactness.
+#: The TPU compiler accepts both levels; what they do to its float
+#: rounding is whatever certification on the chip finds.
 _TIER_OPTIONS = {
     "fast": {"xla_backend_optimization_level": 3},
     "exact": {"xla_backend_optimization_level": 0},
@@ -870,20 +891,35 @@ _TIER_OPTIONS = {
 
 
 class _Compiled:
-    """One traced chunk program + everything the host loop needs.  The
-    trace is lowered once; each executable tier is compiled from it on
-    first use (the fast tier eagerly, so compile errors surface at
-    licence time)."""
+    """One traced chunk program + everything the host loop needs.
+    ``_trace`` fills in the chunk function and its abstract arguments;
+    ``_prepare`` lowers it once; each executable tier is compiled from
+    the lowering on first use (the fast tier eagerly, so compile errors
+    surface before anything runs)."""
 
-    __slots__ = ("sig", "lowered", "tiers", "eager", "cnt_keys",
-                 "buf_names", "scalar_names", "scalar_dtypes", "cw",
-                 "n_warps", "R")
+    __slots__ = ("sig", "chunk_fn", "jitted", "abstract", "lowered", "tiers",
+                 "cnt_keys", "buf_names", "scalar_names", "scalar_dtypes",
+                 "cw", "trace_s", "compile_s", "cert_s")
 
     def executable(self, tier: str):
+        """The compiled program of ``tier``.  A backend compile error is
+        an ``EngineFault`` at site ``jax.compile`` — the launch demotes
+        with the compiler's text in its report — and nothing is cached,
+        so the next launch compiles again."""
         exe = self.tiers.get(tier)
         if exe is None:
-            exe = self.lowered.compile(
-                compiler_options=_TIER_OPTIONS[tier])
+            t0 = perf_counter()
+            try:
+                exe = self.lowered.compile(
+                    compiler_options=_TIER_OPTIONS[tier])
+            except Exception as e:
+                JAX_TELEMETRY["compile_errors"] += 1
+                raise _faults.EngineFault(
+                    f"jax {tier}-tier compile failed on "
+                    f"{'/'.join(_device_key()[:2])}: "
+                    f"{type(e).__name__}: {e}",
+                    site="jax.compile", rung="jax") from e
+            self.compile_s[tier] = perf_counter() - t0
             self.tiers[tier] = exe
         return exe
 
@@ -897,6 +933,8 @@ def _licence(fn: Function, params, n_wg: int, argmap: dict,
         raise LowerError("strict OOB loads")
     if n_wg <= 1:
         raise LowerError("single-workgroup launch")
+    if params.fuel > FUEL_MAX:
+        raise LowerError("fuel budget beyond the int32 counters")
     plan = _interp._decode_plan(fn)
     if plan["ordering_sensitive"]:
         raise LowerError("ordering-sensitive kernel")
@@ -926,13 +964,23 @@ def _licence(fn: Function, params, n_wg: int, argmap: dict,
         raise LowerError("barrier/shared tile with multi-warp rows")
 
 
+def _device_key() -> tuple:
+    """What a verdict and its timings hold for: the platform and kind of
+    the device the program runs on, and the JAX version that compiled
+    it.  A verdict certified on one never promotes a launch on another."""
+    d = jax.devices()[0]
+    return (d.platform, d.device_kind, jax.__version__)
+
+
 def _shape_sig(params, buffers: dict, scalar_args: dict,
                cw: int) -> str:
     """The launch SHAPE CLASS a certification verdict covers: every
     static input of the trace (grid, warp geometry, fuel, chunk width,
-    buffer shapes/dtypes, scalar names) — buffer/scalar VALUES excluded.
+    buffer shapes/dtypes, scalar names) — buffer/scalar VALUES excluded
+    — on one device (``_device_key``).
     """
-    return repr((params.grid, params.grid_y, params.local_size,
+    return repr((_device_key(),
+                 params.grid, params.grid_y, params.local_size,
                  params.local_size_y, params.warp_size, params.fuel,
                  bool(params.strict_oob_loads), cw,
                  tuple(sorted((nm, tuple(b.shape), b.dtype.name)
@@ -950,8 +998,16 @@ def _collect_ops(fn: Function, acc: set, seen: set) -> None:
             _collect_ops(i.operands[0], acc, seen)
 
 
-def _build(fn: Function, params, buffers: dict, scalar_args: dict,
+def _trace(fn: Function, params, buffers: dict, scalar_args: dict,
            cw: int) -> _Compiled:
+    """The chunk function of one (kernel, launch shape) and its abstract
+    arguments: ``chunk_fn(bufs, scalars, c0, acc) -> (bufs, c0 + cw,
+    acc)`` runs workgroups ``c0 .. c0 + cw - 1``; ``acc`` is the packed
+    ``_TraceCtx`` counters carried from chunk to chunk.  ``rec.jitted``
+    donates the buffers, ``c0`` and ``acc``, so each chunk updates them
+    in place and the host loop passes nothing but what the previous
+    chunk returned.  Nothing is traced by JAX yet: lowering
+    ``rec.jitted`` on ``rec.abstract`` does that."""
     W = params.warp_size
     n_warps = params.warps_per_wg
     R = cw * n_warps
@@ -979,13 +1035,20 @@ def _build(fn: Function, params, buffers: dict, scalar_args: dict,
     _collect_ops(fn, ops, set())
     cnt_keys = tuple(sorted(ops))
     fuel_limit = int(params.fuel)
+    n_wg = params.grid * params.grid_y
 
-    def chunk_fn(bufs, scalars, gxr, gyr, valid, fuel_in):
-        tc = _TraceCtx(cnt_keys, fuel_limit, fuel_in)
+    def chunk_fn(bufs, scalars, c0, acc):
+        tc = _TraceCtx(cnt_keys, fuel_limit, acc)
 
         def full(v):
             return jnp.broadcast_to(jnp.int32(v), (R, W))
 
+        ks = c0 + jnp.arange(cw, dtype=jnp.int32)     # workgroup ids
+        valid = jnp.repeat(ks < n_wg, n_warps, total_repeat_length=R)
+        ksc = jnp.where(ks < n_wg, ks, 0)
+        gxr = jnp.repeat(ksc % params.grid, n_warps, total_repeat_length=R)
+        gyr = jnp.repeat(ksc // params.grid, n_warps,
+                         total_repeat_length=R)
         gx2 = jnp.broadcast_to(gxr[:, None], (R, W))
         gy2 = jnp.broadcast_to(gyr[:, None], (R, W))
         intr = {
@@ -1036,69 +1099,79 @@ def _build(fn: Function, params, buffers: dict, scalar_args: dict,
             tc.fuel >= jnp.int32(fuel_limit), jnp.int32(ERR_FUEL),
             jnp.int32(0))
         return (tuple(out.bufs[nm] for nm in buf_names),
-                tuple(tc.cnt[k] for k in cnt_keys),
-                tc.mem, tc.shm, tc.minst, tc.maxd, tc.fuel, tc.err)
+                c0 + jnp.int32(cw), tc.pack())
 
-    # trace + compile now: every LowerError surfaces at licence time,
-    # before anything runs or any verdict is recorded
-    abstract = (
+    i32 = jax.ShapeDtypeStruct((), np.int32)
+    rec = _Compiled()
+    rec.chunk_fn = chunk_fn
+    rec.jitted = jax.jit(chunk_fn, donate_argnums=(0, 2, 3))
+    rec.abstract = (
         tuple(jax.ShapeDtypeStruct(buffers[nm].shape,
                                    buffers[nm].dtype)
               for nm in buf_names),
         tuple(jax.ShapeDtypeStruct((), np.dtype(scalar_dtypes[nm]))
               for nm in scalar_names if nm in scalar_dtypes),
-        jax.ShapeDtypeStruct((R,), np.int32),
-        jax.ShapeDtypeStruct((R,), np.int32),
-        jax.ShapeDtypeStruct((R,), np.bool_),
-        jax.ShapeDtypeStruct((), np.int32))
-
-    rec = _Compiled()
-    rec.lowered = jax.jit(chunk_fn).lower(*abstract)
+        i32,
+        ((i32,) * len(cnt_keys),) + (i32,) * 6)
+    rec.lowered = None
     rec.tiers = {}
-    rec.executable("fast")
-    rec.eager = chunk_fn          # the jax.disable_jit() escape hatch
     rec.cnt_keys = cnt_keys
     rec.buf_names = buf_names
     rec.scalar_names = tuple(nm for nm in scalar_names
                              if nm in scalar_dtypes)
     rec.scalar_dtypes = scalar_dtypes
     rec.cw = cw
-    rec.n_warps = n_warps
-    rec.R = R
+    rec.trace_s = None
+    rec.compile_s = {}
+    rec.cert_s = None
     return rec
+
+
+def _chunk_width(params) -> int:
+    return min(int(_CHUNK_WGS), params.grid * params.grid_y)
 
 
 def _prepare(fn: Function, params, buffers: dict, scalar_args: dict,
              argmap: dict, globals_mem) -> _Compiled:
+    """The traced, lowered program of this (kernel, launch shape), with
+    its fast tier compiled.  A licence miss or a trace failure is a
+    ``LowerError`` and is cached for the kernel's IR version; a backend
+    compile error is an ``EngineFault`` and is not cached."""
     if _faults.ACTIVE:
         _faults.maybe_fault("jax.trace")
-    n_wg = params.grid * params.grid_y
-    cw = min(int(_CHUNK_WGS), n_wg)
+    cw = _chunk_width(params)
     sig = _shape_sig(params, buffers, scalar_args, cw)
     cache = getattr(fn, "_jaxgen_cache", None)
     if cache is None or cache[0] != fn.ir_version:
         cache = (fn.ir_version, {})
         fn._jaxgen_cache = cache  # type: ignore[attr-defined]
-    hit = cache[1].get(sig)
-    if hit is not None:
-        if isinstance(hit, str):
-            raise LowerError(hit)
+    rec = cache[1].get(sig)
+    if isinstance(rec, str):
+        raise LowerError(rec)
+    if rec is not None:
         JAX_TELEMETRY["trace_cache_hits"] += 1
-        return hit
-    try:
-        _licence(fn, params, n_wg, argmap, globals_mem)
-        rec = _build(fn, params, buffers, scalar_args, cw)
-    except _faults.KernelFault:
-        raise
-    except _faults.InjectedFault:
-        raise
-    except Exception as e:
-        reason = (str(e) if isinstance(e, LowerError)
-                  else f"trace failed: {type(e).__name__}: {e}")
-        cache[1][sig] = reason
-        raise LowerError(reason) from e
-    rec.sig = sig
-    cache[1][sig] = rec
+    else:
+        try:
+            _licence(fn, params, params.grid * params.grid_y, argmap,
+                     globals_mem)
+            rec = _trace(fn, params, buffers, scalar_args, cw)
+            # every LowerError surfaces here, at trace time, before
+            # anything runs or any verdict is recorded
+            t0 = perf_counter()
+            rec.lowered = rec.jitted.lower(*rec.abstract)
+            rec.trace_s = perf_counter() - t0
+        except _faults.KernelFault:
+            raise
+        except _faults.InjectedFault:
+            raise
+        except Exception as e:
+            reason = (str(e) if isinstance(e, LowerError)
+                      else f"trace failed: {type(e).__name__}: {e}")
+            cache[1][sig] = reason
+            raise LowerError(reason) from e
+        rec.sig = sig
+        cache[1][sig] = rec
+    rec.executable("fast")
     return rec
 
 
@@ -1113,7 +1186,6 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
     staged device-side and converted at the end, so a faulted launch
     costs nothing to roll back."""
     n_wg = params.grid * params.grid_y
-    cw, n_warps = rec.cw, rec.n_warps
     dev_bufs = tuple(jnp.asarray(buffers[nm]) for nm in rec.buf_names)
     scal = tuple(np.asarray(scalar_args[nm],
                             dtype=rec.scalar_dtypes[nm])
@@ -1121,55 +1193,37 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
     # under jax.disable_jit() run the traced function eagerly — the
     # metamorphic contract: op-by-op eager execution, the AOT-compiled
     # executable and the oracle all agree bit-for-bit
-    run = (rec.eager if jax.config.jax_disable_jit
+    run = (rec.chunk_fn if jax.config.jax_disable_jit
            else rec.executable(tier))
-    fuel = jnp.int32(0)
-    cnt_acc = None
-    mem_acc = shm_acc = minst_acc = maxd_acc = err_acc = None
-    for c0 in range(0, n_wg, cw):
+    c0, acc = np.int32(0), _zero_acc(len(rec.cnt_keys))
+    for _ in range(0, n_wg, rec.cw):
         if _gov.ACTIVE:
             _gov.deadline_check()
         if _faults.ACTIVE:
             _faults.maybe_fault("jax.exec")
-        ks = np.arange(c0, c0 + cw)
-        valid = ks < n_wg
-        ksc = np.where(valid, ks, 0)
-        gxr = np.repeat((ksc % params.grid).astype(np.int32), n_warps)
-        gyr = np.repeat((ksc // params.grid).astype(np.int32), n_warps)
-        vr = np.repeat(valid, n_warps)
-        dev_bufs, cnt, mem_, shm, minst, maxd, fuel, err = run(
-            dev_bufs, scal, gxr, gyr, vr, fuel)
-        if cnt_acc is None:
-            cnt_acc = list(cnt)
-            mem_acc, shm_acc, minst_acc = mem_, shm, minst
-            maxd_acc, err_acc = maxd, err
-        else:
-            cnt_acc = [a + b for a, b in zip(cnt_acc, cnt)]
-            mem_acc = mem_acc + mem_
-            shm_acc = shm_acc + shm
-            minst_acc = minst_acc + minst
-            maxd_acc = jnp.maximum(maxd_acc, maxd)
-            err_acc = err_acc | err
-    err_v = int(err_acc)
+        dev_bufs, c0, acc = run(dev_bufs, scal, c0, acc)
+    cnt, mem_, shm, minst, maxd, _fuel, err = jax.device_get(acc)
+    err_v = int(err)
     if err_v:
         names = [nm for bit, nm in ((ERR_OOB_STORE, "oob-store"),
                                     (ERR_UNIFORM, "uniformity"),
-                                    (ERR_FUEL, "fuel")) if err_v & bit]
+                                    (ERR_FUEL, "fuel"),
+                                    (ERR_OVERFLOW, "counter-overflow"))
+                 if err_v & bit]
         raise _faults.EngineFault(
             f"jax rung semantic-error bits [{', '.join(names)}] in "
             f"@{fn.name} — demoting so the grid rung reproduces the "
-            f"exact kernel error", site="jax.exec", rung="jax")
+            f"exact outcome", site="jax.exec", rung="jax")
     host_bufs = {nm: np.asarray(b)
                  for nm, b in zip(rec.buf_names, dev_bufs)}
-    by_op = {k: int(v) for k, v in zip(rec.cnt_keys, cnt_acc)
-             if int(v)}
+    by_op = {k: int(v) for k, v in zip(rec.cnt_keys, cnt) if int(v)}
     jstats = {
         "instrs": sum(by_op.values()),
         "by_op": by_op,
-        "mem_requests": int(mem_acc),
-        "mem_insts": int(minst_acc),
-        "shared_requests": int(shm_acc),
-        "max_ipdom_depth": int(maxd_acc),
+        "mem_requests": int(mem_),
+        "mem_insts": int(minst),
+        "shared_requests": int(shm),
+        "max_ipdom_depth": int(maxd),
     }
     return host_bufs, jstats
 
@@ -1187,16 +1241,43 @@ def _apply(host_bufs: dict, jstats: dict, buffers: dict,
                                 jstats["max_ipdom_depth"])
 
 
-def _stats_match(jstats: dict, stats) -> bool:
-    return (jstats["instrs"] == stats.instrs
-            and jstats["by_op"] == {k: v for k, v in stats.by_op.items()
-                                    if v}
-            and jstats["mem_requests"] == stats.mem_requests
-            and jstats["mem_insts"] == stats.mem_insts
-            and jstats["shared_requests"] == stats.shared_requests
-            and jstats["max_ipdom_depth"] == stats.max_ipdom_depth
-            and stats.atomic_serial == 0
-            and not stats.prints)
+def _ulps(a, b) -> int:
+    """Distance between two float32 values in units in the last place."""
+    i = np.array([a, b], np.float32).view(np.int32).astype(np.int64)
+    i = np.where(i < 0, -(i & 0x7FFFFFFF), i)
+    return int(abs(i[0] - i[1]))
+
+
+def _disagreement(host_bufs: dict, jstats: dict, buffers: dict,
+                  stats) -> str | None:
+    """None when the jitted run agrees with the oracle bit for bit;
+    otherwise the first difference — a buffer element, with its
+    distance in ulps for float32, or a stat."""
+    for nm in sorted(host_bufs):
+        got, want = host_bufs[nm].reshape(-1), buffers[nm].reshape(-1)
+        if got.tobytes() == want.tobytes():
+            continue
+        u = f"u{got.itemsize}"
+        bad = np.flatnonzero(got.view(u) != want.view(u))
+        i = int(bad[0])
+        ulp = (f", {_ulps(got[i], want[i])} ulp"
+               if got.dtype == np.float32 else "")
+        return (f"{nm}[{i}]: jax {got[i]!r} vs oracle {want[i]!r}{ulp} "
+                f"({bad.size} of {got.size} elements differ)")
+    want_stats = {
+        "instrs": stats.instrs,
+        "by_op": {k: v for k, v in stats.by_op.items() if v},
+        "mem_requests": stats.mem_requests,
+        "mem_insts": stats.mem_insts,
+        "shared_requests": stats.shared_requests,
+        "max_ipdom_depth": stats.max_ipdom_depth,
+    }
+    for k, v in want_stats.items():
+        if jstats[k] != v:
+            return f"stat {k}: jax {jstats[k]} vs oracle {v}"
+    if stats.atomic_serial or stats.prints:
+        return "the oracle serialized atomics or printed"
+    return None
 
 
 # --------------------------------------------------------------------------
@@ -1222,23 +1303,34 @@ def _certs(fn: Function) -> dict:
 
 def _verdict_of(entry) -> tuple:
     """Normalize a cert-store entry to ``(verdict, jax_ms, grid_ms)``.
-    Schema 3 stores the 3-tuple (docs/performance.md "Serve side"): the
-    differential certification run measures the normal chain anyway, so
-    its wall time rides along with the verdict, and the first certified
-    primary fills in the warm jitted time — together they let the
-    dispatch router send launches whose grid time beats the ~0.5 ms
-    jitted-dispatch floor straight to the grid rung.  Plain-string
+    Entries are ``(verdict, jax_ms, grid_ms, detail)`` (docs/performance.md
+    "Serve side"): the differential certification run measures the
+    normal chain anyway, so its wall time rides along with the verdict,
+    and the first certified primary fills in the warm jitted time —
+    together they let the dispatch router send launches whose grid time
+    beats the jitted-dispatch floor straight to the grid rung.
+    ``detail`` says why a verdict is not a plain "pass": the first
+    difference from the oracle, or an exception's text.  Plain-string
     entries (legacy in-memory) mean "no timings yet"."""
     if isinstance(entry, tuple):
-        return entry
+        return entry[:3]
     return (entry, None, None)
+
+
+def _detail_of(entry) -> str:
+    return entry[3] if isinstance(entry, tuple) and len(entry) > 3 else ""
 
 
 def _record(fn: Function, sig: str, verdict: str,
             jax_ms: float | None = None,
-            grid_ms: float | None = None) -> None:
+            grid_ms: float | None = None,
+            detail: str | None = None) -> None:
+    """Store a verdict; ``detail=None`` keeps the entry's detail (a
+    timing update of a verdict already recorded)."""
     certs = _certs(fn)
-    certs[sig] = (verdict, jax_ms, grid_ms)
+    if detail is None:
+        detail = _detail_of(certs.get(sig))
+    certs[sig] = (verdict, jax_ms, grid_ms, detail)
     hooks = _interp.JAX_CERT_HOOKS
     if hooks is not None:
         try:
@@ -1250,6 +1342,31 @@ def _record(fn: Function, sig: str, verdict: str,
 # --------------------------------------------------------------------------
 # public entry points
 # --------------------------------------------------------------------------
+
+def describe(fn: Function, params, buffers: dict,
+             scalar_args: dict | None = None) -> dict:
+    """What this rung holds for one (kernel, launch shape) on this
+    device, without running anything: ``refused`` (the licence or trace
+    refusal, else None), ``verdict`` ("pass", "pass-exact", "fail",
+    "error" or None), its ``tier`` and ``detail``, seconds spent in
+    ``trace_s``, ``compile_s`` (per tier) and ``cert_s``, and the
+    router's ``jax_ms`` / ``grid_ms``."""
+    sig = _shape_sig(params, buffers, scalar_args or {},
+                     _chunk_width(params))
+    cache = getattr(fn, "_jaxgen_cache", None)
+    rec = cache[1].get(sig) if cache and cache[0] == fn.ir_version \
+        else None
+    entry = _certs(fn).get(sig)
+    verdict, jax_ms, grid_ms = _verdict_of(entry)
+    return {"refused": rec if isinstance(rec, str) else None,
+            "verdict": verdict,
+            "tier": {"pass": "fast", "pass-exact": "exact"}.get(verdict),
+            "detail": _detail_of(entry),
+            "trace_s": getattr(rec, "trace_s", None),
+            "compile_s": dict(getattr(rec, "compile_s", None) or {}),
+            "cert_s": getattr(rec, "cert_s", None),
+            "jax_ms": jax_ms, "grid_ms": grid_ms}
+
 
 def licence_check(fn: Function, params, buffers: dict,
                   scalar_args: dict | None = None,
@@ -1274,9 +1391,99 @@ def licence_check(fn: Function, params, buffers: dict,
     try:
         _prepare(fn, params, buffers, scalar_args, argmap,
                  globals_mem or {})
-    except LowerError as e:
+    except _faults.InjectedFault:
+        raise
+    except (LowerError, _faults.EngineFault) as e:
         return (False, str(e))
     return (True, "")
+
+
+def _certify(rec: _Compiled, fn: Function, buffers: dict,
+             scalar_args: dict, params, stats, mode, run_normal) -> bool:
+    """The differential certification run of one (kernel, launch shape):
+    the launch's results come from the normal chain (``run_normal``),
+    and the jitted program's run beside it decides the verdict —
+    "pass" (fast tier bit-exact), "pass-exact" (only the exact tier
+    is), "fail" (the exact tier differs too; the detail names the first
+    difference) or "error" (the jitted program raised; the detail holds
+    the text).  Raises what the normal chain raises, and an infra fault
+    of the jitted run before the normal chain has run (in "fallback"
+    mode it returns False instead: nothing ran)."""
+    JAX_TELEMETRY["cert_runs"] += 1
+    t_cert = perf_counter()
+    # run_normal mutates buffers in place below; the exact tier (tried
+    # only when the fast tier's float bits diverge) replays from the
+    # original inputs, so snapshot them first
+    snap = {nm: buffers[nm].copy() for nm in rec.buf_names}
+    notes = []
+
+    def attempt(tier: str, inputs: dict):
+        try:
+            return _run(rec, fn, inputs, scalar_args, params, tier=tier)
+        except (_faults.KernelFault, _faults.InjectedFault):
+            raise
+        except Exception as e:
+            JAX_TELEMETRY["cert_errors"] += 1
+            notes.append(f"{tier} tier raised {type(e).__name__}: {e}")
+            return None
+
+    try:
+        # reads buffers before run_normal can mutate them; never writes
+        fast = attempt("fast", buffers)
+    except _faults.InjectedFault:
+        # an INFRA fault interrupted the certification — record no
+        # verdict (the pair stays unknown and re-certifies later)
+        JAX_TELEMETRY["demotions"] += 1
+        if mode == "fallback":
+            return False
+        raise
+    try:
+        t0 = perf_counter()
+        run_normal(stats)
+        grid_ms = (perf_counter() - t0) * 1e3
+    except Exception as e:
+        # outcome parity: the caller sees exactly the normal chain's
+        # exception; the pair is pinned to it from now on
+        _record(fn, rec.sig, "fail",
+                detail=f"the normal chain raised {type(e).__name__}: {e}")
+        raise
+    miss = fast and _disagreement(*fast, buffers, stats)
+    if fast and miss is None:
+        # grid_ms rides along with the verdict; jax_ms stays None until
+        # the first certified primary measures the WARM dispatch (the
+        # cert run's timing is polluted by jit compilation)
+        _record(fn, rec.sig, "pass", grid_ms=grid_ms, detail="")
+        JAX_TELEMETRY["certified"] += 1
+        rec.cert_s = perf_counter() - t_cert
+        return True
+    if miss:
+        notes.append(f"fast tier: {miss}")
+    # ---- exact-tier retry -------------------------------------------
+    # the optimized executable diverged (typically FMA-contracted float
+    # accumulation) or raised; replay the snapshot on the separately-
+    # rounded tier against the same oracle results
+    try:
+        exact = attempt("exact", snap)
+    except _faults.InjectedFault:
+        # infra fault mid-retry: the launch's results already came from
+        # the normal chain; leave the pair unknown so a later launch
+        # re-certifies
+        JAX_TELEMETRY["demotions"] += 1
+        return True
+    miss = exact and _disagreement(*exact, buffers, stats)
+    if exact and miss is None:
+        verdict = "pass-exact"
+        JAX_TELEMETRY["certified"] += 1
+    elif exact:
+        verdict = "fail"
+        notes.append(f"exact tier: {miss}")
+    else:
+        verdict = "error"
+    _record(fn, rec.sig, verdict,
+            grid_ms=grid_ms if verdict == "pass-exact" else None,
+            detail="; ".join(notes))
+    rec.cert_s = perf_counter() - t_cert
+    return True
 
 
 def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
@@ -1323,7 +1530,7 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
             raise
     verdict, v_jax_ms, v_grid_ms = _verdict_of(_certs(fn).get(rec.sig))
 
-    if verdict == "fail":
+    if verdict in ("fail", "error"):
         return False
 
     # ---- small-launch dispatch router --------------------------------
@@ -1342,76 +1549,8 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
         return False
 
     if verdict is None:
-        # ---- differential certification run -------------------------
-        JAX_TELEMETRY["cert_runs"] += 1
-        # run_normal mutates buffers in place below; the exact tier
-        # (tried only when the fast tier's float bits diverge) replays
-        # from the original inputs, so snapshot them first
-        snap = {nm: buffers[nm].copy() for nm in rec.buf_names}
-        jok = True
-        host_bufs = jstats = None
-        try:
-            # reads buffers before run_normal can mutate them; never
-            # writes them
-            host_bufs, jstats = _run(rec, fn, buffers, scalar_args,
-                                     params, tier="fast")
-        except _faults.KernelFault:
-            raise                       # deadline: the caller's verdict
-        except _faults.InjectedFault:
-            # an INFRA fault interrupted the certification — record no
-            # verdict (the pair stays unknown and re-certifies later)
-            JAX_TELEMETRY["demotions"] += 1
-            if mode == "fallback":
-                return False
-            raise
-        except Exception:
-            jok = False
-        try:
-            t0 = perf_counter()
-            run_normal(stats)
-            grid_ms = (perf_counter() - t0) * 1e3
-        except Exception:
-            # outcome parity: the caller sees exactly the normal
-            # chain's exception; the pair is pinned to it from now on
-            _record(fn, rec.sig, "fail")
-            raise
-
-        def _agrees(hb, js):
-            return (_stats_match(js, stats)
-                    and all(hb[nm].tobytes() == buffers[nm].tobytes()
-                            for nm in rec.buf_names))
-
-        if jok and _agrees(host_bufs, jstats):
-            # grid_ms rides along with the verdict; jax_ms stays None
-            # until the first certified primary measures the WARM
-            # dispatch (the cert run's timing is polluted by jit
-            # compilation)
-            _record(fn, rec.sig, "pass", grid_ms=grid_ms)
-            JAX_TELEMETRY["certified"] += 1
-            return True
-        # ---- exact-tier retry ---------------------------------------
-        # the optimized executable diverged (typically FMA-contracted
-        # float accumulation); replay the snapshot on the separately-
-        # rounded tier against the same oracle results
-        try:
-            ehost, ejstats = _run(rec, fn, snap, scalar_args, params,
-                                  tier="exact")
-        except _faults.KernelFault:
-            raise
-        except _faults.InjectedFault:
-            # infra fault mid-retry: the launch's results already came
-            # from the normal chain; leave the pair unknown so a later
-            # launch re-certifies
-            JAX_TELEMETRY["demotions"] += 1
-            return True
-        except Exception:
-            ehost = None
-        ok = ehost is not None and _agrees(ehost, ejstats)
-        _record(fn, rec.sig, "pass-exact" if ok else "fail",
-                grid_ms=grid_ms if ok else None)
-        if ok:
-            JAX_TELEMETRY["certified"] += 1
-        return True
+        return _certify(rec, fn, buffers, scalar_args, params, stats,
+                        mode, run_normal)
 
     # ---- certified primary ------------------------------------------
     tier = "exact" if verdict == "pass-exact" else "fast"

@@ -68,8 +68,8 @@ _TY_DTYPE = {Ty.I32: np.int32, Ty.F32: np.float32, Ty.BOOL: np.bool_}
 #     body changes the IR hash, so stale entries can never be returned;
 #     unreadable/corrupt entries fall back to a fresh compile.
 #
-# Disk location: $VOLT_CACHE_DIR, else ~/.cache/volt_repro.  Disable with
-# VOLT_DISK_CACHE=0.
+# Disk location: $VOLT_CACHE_DIR, else <checkout>/.cache/volt.  Disable
+# with VOLT_DISK_CACHE=0.
 # --------------------------------------------------------------------------
 
 _COMPILE_CACHE: Dict[Tuple, Tuple[Any, CompiledKernel]] = {}
@@ -130,13 +130,30 @@ def _compiler_fingerprint() -> str:
 _COMPILER_FP: Optional[str] = None
 
 
+#: caches the program keeps inside its own checkout (git ignores it)
+CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".cache"
+
+
 def disk_cache_dir() -> Optional[Path]:
     if os.environ.get("VOLT_DISK_CACHE", "1") == "0":
         return None
     d = os.environ.get("VOLT_CACHE_DIR")
     if d:
         return Path(d)
-    return Path.home() / ".cache" / "volt_repro"
+    return CHECKOUT_CACHE / "volt"
+
+
+def use_jax_compile_cache() -> None:
+    """Point JAX's persistent compilation cache at a fixed path: the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names when it is set (JAX
+    reads that itself, so nothing is set here), else
+    ``<checkout>/.cache/jax``.  Called by the programs that reach the
+    device, never on import, so a test run leaves the checkout alone."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir",
+                      str(CHECKOUT_CACHE / "jax"))
 
 
 def _disk_key(module: Module, kernel_name: str, config: PassConfig,
@@ -386,13 +403,16 @@ _interp.DECODE_PLAN_HOOKS = (_decode_plan_load, _decode_plan_save)
 # schema 3: verdicts carry measured (jax_ms, grid_ms) per launch-shape
 # class so the dispatch router can send small launches straight to the
 # grid rung (the ~0.5 ms jitted-dispatch floor fix); schema-2 verdicts
-# lack the timings and are discarded wholesale
-_JAX_CERT_SCHEMA = 3
+# lack the timings and are discarded wholesale.
+# schema 4: each verdict carries a detail (the first difference from the
+# oracle, or the exception a tier raised), and its shape signature names
+# the device it was certified on (platform, device_kind, JAX version)
+_JAX_CERT_SCHEMA = 4
 
 
 def _jax_cert_load(fn: Function) -> Optional[dict]:
     """.vjc read: the jax rung's differential-certification verdicts
-    ({launch-shape-sig: "pass" | "pass-exact" | "fail"}), keyed by the
+    ({launch-shape-sig: (verdict, jax_ms, grid_ms, detail)}), keyed by the
     same kernel content hash as the .vck/.vdp caches — an IR change
     invalidates every verdict with it."""
     d = disk_cache_dir()
@@ -529,17 +549,10 @@ REPORT_RING = 32
 def _attach_report(e: BaseException, report: LaunchReport) -> None:
     """Attach the degradation history to a SURFACING exception:
     ``e.report`` for programmatic use, plus the one-line summary as an
-    exception note (or an args suffix before 3.11) so a traceback shows
-    which rungs were tried."""
+    exception note (``e.__notes__``) so a traceback shows which rungs
+    were tried."""
     e.report = report                       # type: ignore[attr-defined]
-    note = "launch report: " + report.summary()
-    add = getattr(e, "add_note", None)
-    if add is not None:
-        add(note)
-    elif e.args and isinstance(e.args[0], str):
-        e.args = (f"{e.args[0]}\n  {note}",) + e.args[1:]
-    else:
-        e.args = e.args + (note,)
+    e.add_note("launch report: " + report.summary())
 
 
 #: process-lifetime launch/degradation counters (GRID_TELEMETRY's

@@ -15,8 +15,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def gpipe_forward(layer_fn: Callable, n_microbatches: int, axis: str = "pipe",
@@ -78,4 +78,4 @@ def make_pipelined_apply(mesh: Mesh, layer_fn: Callable,
         staged, mesh=mesh,
         in_specs=(P(axis), P(None)),
         out_specs=P(None),
-        check_rep=False)
+        check_vma=False)

@@ -101,15 +101,7 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() across jax versions: older jax returns a
-    flat dict, newer jax a single-element list of dicts (one per
-    computation)."""
-    c = compiled.cost_analysis()
-    if c is None:
-        return {}
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
-    return dict(c)
+    return dict(compiled.cost_analysis() or {})
 
 
 def _mem_analysis(compiled) -> dict:

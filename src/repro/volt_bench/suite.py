@@ -464,6 +464,9 @@ def atomic_agg(x: "ptr_f32 const", counter: "ptr_i32", n: "i32 uniform"):
 
 @dataclass
 class Bench:
+    """``make(rng)`` builds the suite-size inputs; the makers of
+    vecadd, reduce0, sgemm and spmv_csr also take ``size=`` for launches
+    at the sizes their users run."""
     name: str
     handle: Any
     make: Callable[[np.random.Generator], Tuple[Dict[str, np.ndarray],
@@ -480,9 +483,13 @@ def _params(grid: int) -> LaunchParams:
     return LaunchParams(grid=grid, local_size=32, warp_size=32)
 
 
-def _mk_vecadd(rng):
-    n = 200
-    g = 8
+def _groups(n: int) -> int:
+    """Workgroups of 32 threads that cover ``n`` work items."""
+    return -(-n // 32)
+
+
+def _mk_vecadd(rng, size=None):
+    n, g = (200, 8) if size is None else (size, _groups(size))
     x = rng.standard_normal(g * 32).astype(np.float32)
     y = rng.standard_normal(g * 32).astype(np.float32)
     z = np.zeros(g * 32, np.float32)
@@ -543,17 +550,17 @@ def _ref_transpose(bufs, sc):
     return {**bufs, "y": y}
 
 
-def _mk_reduce0(rng):
-    g = 8
+def _mk_reduce0(rng, size=None):
+    n, g = (230, 8) if size is None else (size, _groups(size))
     x = rng.standard_normal(g * 32).astype(np.float32)
-    return {"x": x, "out": np.zeros(g, np.float32)}, {"n": 230}, _params(g)
+    return {"x": x, "out": np.zeros(g, np.float32)}, {"n": n}, _params(g)
 
 
 def _ref_reduce0(bufs, sc):
     n = sc["n"]
     xm = bufs["x"].copy()
     xm[n:] = 0
-    return {**bufs, "out": xm.reshape(8, 32).sum(1).astype(np.float32)}
+    return {**bufs, "out": xm.reshape(-1, 32).sum(1).astype(np.float32)}
 
 
 def _mk_psum(rng):
@@ -608,10 +615,10 @@ def _ref_sfilter(bufs, sc):
     return {**bufs, "y": y}
 
 
-def _mk_sgemm(rng):
-    m = n = 16
-    k = 8
-    g = 8
+def _mk_sgemm(rng, size=None):
+    """``size`` is the square edge: m = n = k = size."""
+    m, n, k, g = (16, 16, 8, 8) if size is None \
+        else (size, size, size, _groups(size * size))
     a = rng.standard_normal(m * k).astype(np.float32)
     b = rng.standard_normal(k * n).astype(np.float32)
     return {"a": a, "b": b, "c": np.zeros(g * 32, np.float32)}, \
@@ -816,10 +823,12 @@ def _ragged_csr(rng, n: int, base_deg: int = 16,
     return row_ptr, cols
 
 
-def _mk_spmv_csr(rng):
-    g = 16
+def _mk_spmv_csr(rng, size=None, base_deg=16):
+    """``size`` rows; ``base_deg`` scales the degree distribution
+    (short rows below it, hot rows up to twice it, empty rows kept)."""
+    g = 16 if size is None else _groups(size)
     n = g * 32
-    row_ptr, cols = _ragged_csr(rng, n)
+    row_ptr, cols = _ragged_csr(rng, n, base_deg, 2 * base_deg)
     vals = rng.standard_normal(len(cols)).astype(np.float32)
     x = rng.standard_normal(n).astype(np.float32)
     return {"row_ptr": row_ptr, "cols": cols, "vals": vals, "x": x,
@@ -1071,3 +1080,15 @@ BENCHES: Dict[str, Bench] = {
 
 def get_bench(name: str) -> Bench:
     return BENCHES[name]
+
+
+#: maker arguments for the sizes users launch these kernels at (the
+#: suite's own sizes above are test sizes): vecadd over 2^22 elements,
+#: a 512^3 sgemm, spmv_csr over 2^18 rows averaging ~16 nonzeros each
+#: in the suite's ragged degree shape, reduce0 over 2^22 elements
+USER_SIZES: Dict[str, Dict[str, int]] = {
+    "vecadd": {"size": 2**22},
+    "sgemm": {"size": 512},
+    "spmv_csr": {"size": 2**18, "base_deg": 36},
+    "reduce0": {"size": 2**22},
+}
