@@ -68,6 +68,7 @@ from .. import governor as _gov
 from .. import interp as _interp
 from ..interp_mem import CACHE_LINE_ELEMS
 from ..passes.analysis import export_codegen_facts
+from ..spans import span
 
 _TY_DTYPE = {Ty.I32: jnp.int32, Ty.F32: jnp.float32, Ty.BOOL: jnp.bool_}
 _TY_NP = {Ty.I32: np.int32, Ty.F32: np.float32, Ty.BOOL: np.bool_}
@@ -112,6 +113,8 @@ JAX_TELEMETRY = {
     "routed_small": 0,   # certified but sent to the grid rung: the
                          # measured grid time beats the jitted-dispatch
                          # floor at this launch-shape class
+    "upload_bytes": 0,   # bound buffers copied to the device and back
+    "download_bytes": 0, # by the launches counted in "engaged"
 }
 
 #: route a certified launch to the grid rung when the measured grid
@@ -1186,23 +1189,26 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
     staged device-side and converted at the end, so a faulted launch
     costs nothing to roll back."""
     n_wg = params.grid * params.grid_y
-    dev_bufs = tuple(jnp.asarray(buffers[nm]) for nm in rec.buf_names)
-    scal = tuple(np.asarray(scalar_args[nm],
-                            dtype=rec.scalar_dtypes[nm])
-                 for nm in rec.scalar_names)
+    with span("volt.jax.upload"):
+        dev_bufs = tuple(jnp.asarray(buffers[nm]) for nm in rec.buf_names)
+        scal = tuple(np.asarray(scalar_args[nm],
+                                dtype=rec.scalar_dtypes[nm])
+                     for nm in rec.scalar_names)
     # under jax.disable_jit() run the traced function eagerly — the
     # metamorphic contract: op-by-op eager execution, the AOT-compiled
     # executable and the oracle all agree bit-for-bit
     run = (rec.chunk_fn if jax.config.jax_disable_jit
            else rec.executable(tier))
     c0, acc = np.int32(0), _zero_acc(len(rec.cnt_keys))
-    for _ in range(0, n_wg, rec.cw):
-        if _gov.ACTIVE:
-            _gov.deadline_check()
-        if _faults.ACTIVE:
-            _faults.maybe_fault("jax.exec")
-        dev_bufs, c0, acc = run(dev_bufs, scal, c0, acc)
-    cnt, mem_, shm, minst, maxd, _fuel, err = jax.device_get(acc)
+    with span("volt.jax.dispatch"):
+        for _ in range(0, n_wg, rec.cw):
+            if _gov.ACTIVE:
+                _gov.deadline_check()
+            if _faults.ACTIVE:
+                _faults.maybe_fault("jax.exec")
+            dev_bufs, c0, acc = run(dev_bufs, scal, c0, acc)
+    with span("volt.jax.sync"):
+        cnt, mem_, shm, minst, maxd, _fuel, err = jax.device_get(acc)
     err_v = int(err)
     if err_v:
         names = [nm for bit, nm in ((ERR_OOB_STORE, "oob-store"),
@@ -1214,8 +1220,9 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
             f"jax rung semantic-error bits [{', '.join(names)}] in "
             f"@{fn.name} — demoting so the grid rung reproduces the "
             f"exact outcome", site="jax.exec", rung="jax")
-    host_bufs = {nm: np.asarray(b)
-                 for nm, b in zip(rec.buf_names, dev_bufs)}
+    with span("volt.jax.download"):
+        host_bufs = {nm: np.asarray(b)
+                     for nm, b in zip(rec.buf_names, dev_bufs)}
     by_op = {k: int(v) for k, v in zip(rec.cnt_keys, cnt) if int(v)}
     jstats = {
         "instrs": sum(by_op.values()),
@@ -1507,8 +1514,9 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
     jax-vs-grid benchmarks) keep unconditional engagement.
     """
     try:
-        rec = _prepare(fn, params, buffers, scalar_args, argmap,
-                       mem.globals_mem)
+        with span("volt.jax.prepare"):
+            rec = _prepare(fn, params, buffers, scalar_args, argmap,
+                           mem.globals_mem)
     except LowerError:
         JAX_TELEMETRY["refusals"] += 1
         return False
@@ -1549,8 +1557,9 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
         return False
 
     if verdict is None:
-        return _certify(rec, fn, buffers, scalar_args, params, stats,
-                        mode, run_normal)
+        with span("volt.jax.certify"):
+            return _certify(rec, fn, buffers, scalar_args, params, stats,
+                            mode, run_normal)
 
     # ---- certified primary ------------------------------------------
     tier = "exact" if verdict == "pass-exact" else "fast"
@@ -1572,8 +1581,13 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
         raise _faults.EngineFault(
             f"jax executor failure: {type(e).__name__}: {e}",
             site="jax.exec", rung="jax") from e
-    _apply(host_bufs, jstats, buffers, stats)
+    with span("volt.jax.apply"):
+        _apply(host_bufs, jstats, buffers, stats)
     JAX_TELEMETRY["engaged"] += 1
+    JAX_TELEMETRY["upload_bytes"] += sum(buffers[nm].nbytes
+                                         for nm in rec.buf_names)
+    JAX_TELEMETRY["download_bytes"] += sum(a.nbytes
+                                           for a in host_bufs.values())
     if v_jax_ms is None:
         # first warm primary at this shape class: measure the jitted
         # wall (dispatch floor included) so the router has both sides

@@ -48,6 +48,7 @@ from .interp import ExecError, ExecStats, LaunchParams, \
 from .passes.pipeline import CompiledKernel, PassConfig, run_pipeline
 from .passes.uniformity import UniformityInfo
 from .simx import CycleModel
+from .spans import span
 from .vir import Function, Module, Op, Ty
 
 _TY_DTYPE = {Ty.I32: np.int32, Ty.F32: np.float32, Ty.BOOL: np.bool_}
@@ -761,7 +762,8 @@ class Runtime:
             report.snapshot_skipped = "mem-budget"
             _tel("snapshot_budget_skips")
             return None
-        snap: Dict[Any, Any] = {k: a.copy() for k, a in pairs}
+        with span("volt.launch.snapshot"):
+            snap: Dict[Any, Any] = {k: a.copy() for k, a in pairs}
         snap["__globals_keys__"] = set(gmem)
         report.snapshot_bytes = total
         return snap
@@ -772,17 +774,18 @@ class Runtime:
                   ) -> None:
         bufs = self.buffers if buffers is None else buffers
         gmem = self.globals_mem if globals_mem is None else globals_mem
-        for key, arr in snap.items():
-            if not isinstance(key, tuple):
-                continue
-            kind, name = key
-            dst = bufs[name] if kind == "b" else gmem[name]
-            dst[:] = arr
-        # globals the failed attempt lazily zero-created: drop them so
-        # the retry re-creates them identically
-        for name in list(gmem):
-            if name not in snap["__globals_keys__"]:
-                del gmem[name]
+        with span("volt.launch.rollback"):
+            for key, arr in snap.items():
+                if not isinstance(key, tuple):
+                    continue
+                kind, name = key
+                dst = bufs[name] if kind == "b" else gmem[name]
+                dst[:] = arr
+            # globals the failed attempt lazily zero-created: drop them
+            # so the retry re-creates them identically
+            for name in list(gmem):
+                if name not in snap["__globals_keys__"]:
+                    del gmem[name]
 
     def launch(self, kernel_fn: Function, *, grid: int, block: int,
                scalar_args: Optional[Dict[str, Any]] = None,
@@ -794,7 +797,20 @@ class Runtime:
         ``buffers``/``globals_mem`` override the Runtime-owned dicts —
         the launch service runs each tenant's launch against the
         tenant's own buffer set while sharing this Runtime's breaker
-        bank, governor, pool and report ring."""
+        bank, governor, pool and report ring.  The whole call is the
+        ``volt.launch`` span."""
+        with span("volt.launch"):
+            return self._launch(kernel_fn, grid=grid, block=block,
+                                scalar_args=scalar_args,
+                                deadline_ms=deadline_ms, buffers=buffers,
+                                globals_mem=globals_mem, fuel=fuel)
+
+    def _launch(self, kernel_fn: Function, *, grid: int, block: int,
+                scalar_args: Optional[Dict[str, Any]],
+                deadline_ms: Optional[float],
+                buffers: Optional[Dict[str, np.ndarray]],
+                globals_mem: Optional[Dict[str, np.ndarray]],
+                fuel: Optional[int]) -> ExecStats:
         bufs = self.buffers if buffers is None else buffers
         gmem = self.globals_mem if globals_mem is None else globals_mem
         # materialize staged symbols now that "addresses are resolved"
