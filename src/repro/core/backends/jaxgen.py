@@ -6,10 +6,12 @@ privacy (every store index injective across the launch), structured
 control flow (post-``structurize`` every loop is a ``vx_pred``/uniform
 header loop and every divergent branch a ``vx_split``/``vx_join``
 diamond).  This module consumes those licences and emits ONE traced,
-``jax.jit``-compiled chunk function over ``(rows, W)`` activation
-arrays — rows are warps, ``n_warps`` consecutive rows per workgroup,
-exactly the grid executor's row layout — instead of walking one Python
-handler per decoded node:
+``jax.jit``-compiled program per launch shape instead of walking one
+Python handler per decoded node.  The program loops over chunks of
+workgroups on the device (``lax.while_loop``); each iteration runs one
+chunk over ``(rows, W)`` activation arrays — rows are warps,
+``n_warps`` consecutive rows per workgroup, exactly the grid executor's
+row layout:
 
   * masks become ``jnp.where`` / masked scatters (``.at[...].set(...,
     mode="drop")``);
@@ -73,9 +75,15 @@ from ..spans import span
 _TY_DTYPE = {Ty.I32: jnp.int32, Ty.F32: jnp.float32, Ty.BOOL: jnp.bool_}
 _TY_NP = {Ty.I32: np.int32, Ty.F32: np.float32, Ty.BOOL: np.bool_}
 
-#: workgroups per jitted chunk (module attribute so the metamorphic
-#: suite can vary it; the compiled-record key includes the value)
+#: workgroups per chunk, one iteration of the program's device-side
+#: loop (module attribute so the metamorphic suite can vary it; the
+#: compiled-record key includes the value)
 _CHUNK_WGS = 256
+
+#: the layout of the compiled program, part of every shape signature:
+#: the chunk loop runs inside one executable.  A verdict certified for
+#: another layout (one executable call per chunk) never promotes this one
+_PROGRAM = "device-chunk-loop"
 
 #: sorts-after-everything sentinel for masked-out line keys (valid line
 #: keys are element_index // CACHE_LINE_ELEMS <= 2**27)
@@ -115,6 +123,8 @@ JAX_TELEMETRY = {
                          # floor at this launch-shape class
     "upload_bytes": 0,   # bound buffers copied to the device and back
     "download_bytes": 0, # by the launches counted in "engaged"
+    "dispatches": 0,     # executable calls made by those launches: one
+                         # each, one per chunk while a deadline is armed
 }
 
 #: route a certified launch to the grid rung when the measured grid
@@ -894,13 +904,13 @@ _TIER_OPTIONS = {
 
 
 class _Compiled:
-    """One traced chunk program + everything the host loop needs.
-    ``_trace`` fills in the chunk function and its abstract arguments;
+    """One traced program + everything the host side of a launch needs.
+    ``_trace`` fills in the looped function and its abstract arguments;
     ``_prepare`` lowers it once; each executable tier is compiled from
     the lowering on first use (the fast tier eagerly, so compile errors
     surface before anything runs)."""
 
-    __slots__ = ("sig", "chunk_fn", "jitted", "abstract", "lowered", "tiers",
+    __slots__ = ("sig", "program", "jitted", "abstract", "lowered", "tiers",
                  "cnt_keys", "buf_names", "scalar_names", "scalar_dtypes",
                  "cw", "trace_s", "compile_s", "cert_s")
 
@@ -980,9 +990,10 @@ def _shape_sig(params, buffers: dict, scalar_args: dict,
     """The launch SHAPE CLASS a certification verdict covers: every
     static input of the trace (grid, warp geometry, fuel, chunk width,
     buffer shapes/dtypes, scalar names) — buffer/scalar VALUES excluded
-    — on one device (``_device_key``).
+    — on one device (``_device_key``), for one program layout
+    (``_PROGRAM``).
     """
-    return repr((_device_key(),
+    return repr((_device_key(), _PROGRAM,
                  params.grid, params.grid_y, params.local_size,
                  params.local_size_y, params.warp_size, params.fuel,
                  bool(params.strict_oob_loads), cw,
@@ -1003,14 +1014,17 @@ def _collect_ops(fn: Function, acc: set, seen: set) -> None:
 
 def _trace(fn: Function, params, buffers: dict, scalar_args: dict,
            cw: int) -> _Compiled:
-    """The chunk function of one (kernel, launch shape) and its abstract
-    arguments: ``chunk_fn(bufs, scalars, c0, acc) -> (bufs, c0 + cw,
-    acc)`` runs workgroups ``c0 .. c0 + cw - 1``; ``acc`` is the packed
-    ``_TraceCtx`` counters carried from chunk to chunk.  ``rec.jitted``
-    donates the buffers, ``c0`` and ``acc``, so each chunk updates them
-    in place and the host loop passes nothing but what the previous
-    chunk returned.  Nothing is traced by JAX yet: lowering
-    ``rec.jitted`` on ``rec.abstract`` does that."""
+    """The program of one (kernel, launch shape) and its abstract
+    arguments: ``program(bufs, scalars, c_lo, c_hi, acc) -> (bufs,
+    acc)`` runs the chunks of workgroups ``c_lo, c_lo + cw, ...`` below
+    ``c_hi`` in a device-side loop, one ``chunk_fn`` per iteration;
+    ``acc`` is the packed ``_TraceCtx`` counters carried from chunk to
+    chunk.  The loop carries every buffer: XLA turns the ones no chunk
+    writes into loop-invariant operands itself.  ``rec.jitted`` donates
+    the buffers and ``acc``, so one call updates them in place, and a
+    shorter ``[c_lo, c_hi)`` steps the same executable.  Nothing is
+    traced by JAX yet: lowering ``rec.jitted`` on ``rec.abstract`` does
+    that."""
     W = params.warp_size
     n_warps = params.warps_per_wg
     R = cw * n_warps
@@ -1104,17 +1118,24 @@ def _trace(fn: Function, params, buffers: dict, scalar_args: dict,
         return (tuple(out.bufs[nm] for nm in buf_names),
                 c0 + jnp.int32(cw), tc.pack())
 
+    def program(bufs, scalars, c_lo, c_hi, acc):
+        bufs, _, acc = jax.lax.while_loop(
+            lambda carry: carry[1] < c_hi,
+            lambda carry: chunk_fn(carry[0], scalars, carry[1], carry[2]),
+            (bufs, c_lo, acc))
+        return bufs, acc
+
     i32 = jax.ShapeDtypeStruct((), np.int32)
     rec = _Compiled()
-    rec.chunk_fn = chunk_fn
-    rec.jitted = jax.jit(chunk_fn, donate_argnums=(0, 2, 3))
+    rec.program = program
+    rec.jitted = jax.jit(program, donate_argnums=(0, 4))
     rec.abstract = (
         tuple(jax.ShapeDtypeStruct(buffers[nm].shape,
                                    buffers[nm].dtype)
               for nm in buf_names),
         tuple(jax.ShapeDtypeStruct((), np.dtype(scalar_dtypes[nm]))
               for nm in scalar_names if nm in scalar_dtypes),
-        i32,
+        i32, i32,
         ((i32,) * len(cnt_keys),) + (i32,) * 6)
     rec.lowered = None
     rec.tiers = {}
@@ -1179,15 +1200,19 @@ def _prepare(fn: Function, params, buffers: dict, scalar_args: dict,
 
 
 # --------------------------------------------------------------------------
-# host loop
+# host side of a launch
 # --------------------------------------------------------------------------
 
 def _run(rec: _Compiled, fn: Function, buffers: dict,
          scalar_args: dict, params, tier: str = "fast") -> tuple:
     """Run every chunk on the given executable tier; returns
-    (host_bufs, jstats dict).  Never mutates ``buffers`` — results are
-    staged device-side and converted at the end, so a faulted launch
-    costs nothing to roll back."""
+    (host_bufs, jstats dict), ``jstats["dispatches"]`` the executable
+    calls made.  One call runs the whole grid; while a deadline is armed
+    the same executable is stepped a chunk at a time, with the deadline
+    checked before each call.  Site ``jax.exec`` is checked before each
+    call and once after the last.  Never mutates ``buffers`` — results
+    are staged device-side and converted at the end, so a faulted
+    launch costs nothing to roll back."""
     n_wg = params.grid * params.grid_y
     with span("volt.jax.upload"):
         dev_bufs = tuple(jnp.asarray(buffers[nm]) for nm in rec.buf_names)
@@ -1197,16 +1222,22 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
     # under jax.disable_jit() run the traced function eagerly — the
     # metamorphic contract: op-by-op eager execution, the AOT-compiled
     # executable and the oracle all agree bit-for-bit
-    run = (rec.chunk_fn if jax.config.jax_disable_jit
+    run = (rec.program if jax.config.jax_disable_jit
            else rec.executable(tier))
-    c0, acc = np.int32(0), _zero_acc(len(rec.cnt_keys))
+    acc = _zero_acc(len(rec.cnt_keys))
+    step = rec.cw if _gov.ACTIVE else n_wg
+    calls = 0
     with span("volt.jax.dispatch"):
-        for _ in range(0, n_wg, rec.cw):
+        for c in range(0, n_wg, step):
             if _gov.ACTIVE:
                 _gov.deadline_check()
             if _faults.ACTIVE:
                 _faults.maybe_fault("jax.exec")
-            dev_bufs, c0, acc = run(dev_bufs, scal, c0, acc)
+            dev_bufs, acc = run(dev_bufs, scal, np.int32(c),
+                                np.int32(min(c + step, n_wg)), acc)
+            calls += 1
+        if _faults.ACTIVE:
+            _faults.maybe_fault("jax.exec")
     with span("volt.jax.sync"):
         cnt, mem_, shm, minst, maxd, _fuel, err = jax.device_get(acc)
     err_v = int(err)
@@ -1231,6 +1262,7 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
         "mem_insts": int(minst),
         "shared_requests": int(shm),
         "max_ipdom_depth": int(maxd),
+        "dispatches": calls,
     }
     return host_bufs, jstats
 
@@ -1584,6 +1616,7 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
     with span("volt.jax.apply"):
         _apply(host_bufs, jstats, buffers, stats)
     JAX_TELEMETRY["engaged"] += 1
+    JAX_TELEMETRY["dispatches"] += jstats["dispatches"]
     JAX_TELEMETRY["upload_bytes"] += sum(buffers[nm].nbytes
                                          for nm in rec.buf_names)
     JAX_TELEMETRY["download_bytes"] += sum(a.nbytes

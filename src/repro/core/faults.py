@@ -151,11 +151,14 @@ register_site("parallel.worker.exec", "host-parallel dispatcher: chunk "
               "deterministic under any thread schedule")
 register_site("parallel.merge", "host-parallel dispatcher: deterministic "
               "chunk-order merge of per-chunk stats/telemetry")
-# jax codegen rung (core/backends/jaxgen.py): licence + trace, chunked
-# jitted execution, certification-cache read — all scoped, so a faulted
-# jax launch demotes to the grid rung with buffers untouched ----------------
-register_site("jax.trace", "jaxgen licence check + chunk-function trace")
-register_site("jax.exec", "jaxgen per-chunk jitted execution")
+# jax codegen rung (core/backends/jaxgen.py): licence + trace, jitted
+# execution, certification-cache read — all scoped, so a faulted jax
+# launch demotes to the grid rung with buffers untouched --------------------
+register_site("jax.trace", "jaxgen licence check + program trace")
+register_site("jax.exec", "jaxgen jitted execution: checked before each "
+              "executable call (one per launch, one per chunk while a "
+              "deadline is armed) and once after the last call returns, "
+              "before its results are read back")
 register_site("jax.cache.load", "jax certification-cache read (.vjc "
               "deserialize / in-memory verdict lookup)")
 # serve engine: per-request recovery (retry with backoff, then fail the
