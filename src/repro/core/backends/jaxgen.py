@@ -19,7 +19,13 @@ row layout:
     (the oracle's own execution order for a warp that takes both);
   * ``vx_pred`` and uniform header loops become ``lax.while_loop`` with
     a carry of (written slots, written buffers, header-defined regs,
-    live mask, stat counters);
+    live mask, stat counters, count of live rows).  A ``vx_pred`` loop
+    compacts its rows, the device counterpart of the grid executor's
+    ``interp._compact_grid`` under the same licence (private stores, no
+    buffer both loaded and stored): it runs in stages on fewer and
+    fewer rows (``_ladder``), gathering the live rows, whole warps, into
+    each next stage and scattering them back after the last, so the
+    body is not paid for on rows that have left the loop;
   * lockstep barriers are no-ops (the rung only licenses barriers at
     ``n_warps == 1``, where a row IS the whole workgroup);
   * loads/stores lower to gathers/scatters; store injectivity comes
@@ -55,6 +61,7 @@ follow the PR 6/7 contracts unchanged.
 """
 from __future__ import annotations
 
+from contextlib import contextmanager
 from time import perf_counter
 
 import numpy as np
@@ -80,10 +87,19 @@ _TY_NP = {Ty.I32: np.int32, Ty.F32: np.float32, Ty.BOOL: np.bool_}
 #: compiled-record key includes the value)
 _CHUNK_WGS = 256
 
+#: row compaction of ``vx_pred`` loops: each stage of a compacted loop
+#: runs on ``1 / _COMPACT_STEP`` of the rows of the stage before it
+#: (``_ladder``)
+_COMPACT_STEP = 4
+
 #: the layout of the compiled program, part of every shape signature:
 #: the chunk loop runs inside one executable.  A verdict certified for
 #: another layout (one executable call per chunk) never promotes this one
 _PROGRAM = "device-chunk-loop"
+
+#: intrinsics with one value in every lane of a launch (its shape)
+_LAUNCH_INTR = {"local_size", "num_groups", "global_size", "num_threads",
+                "num_warps", "grid_dim"}
 
 #: sorts-after-everything sentinel for masked-out line keys (valid line
 #: keys are element_index // CACHE_LINE_ELEMS <= 2**27)
@@ -125,7 +141,17 @@ JAX_TELEMETRY = {
     "download_bytes": 0, # by the launches counted in "engaged"
     "dispatches": 0,     # executable calls made by those launches: one
                          # each, one per chunk while a deadline is armed
+    # device-side loops of those launches, from the counter pack:
+    "loop_trips": 0,     # loop iterations executed (every stage, chunk)
+    "loop_rows_paid": 0, # rows the loop body was computed for
+    "loop_rows_live": 0, # of those, rows with a live lane
+    "loop_compactions": 0,  # live rows gathered into a narrower stage
 }
+
+#: the loop counters of the counter pack, in pack order (the pack holds
+#: them only for kernels with a loop)
+LOOP_KEYS = ("loop_trips", "loop_rows_paid", "loop_rows_live",
+             "loop_compactions")
 
 #: route a certified launch to the grid rung when the measured grid
 #: time is below this fraction of the measured jax time — the margin
@@ -153,12 +179,14 @@ def _scan_fn(fn: Function) -> dict:
     if cached is not None and cached[0] == fn.ir_version:
         return cached[1]
     out = {"refused": set(), "barrier": False, "shared": False,
-           "global": False, "recursive": False}
+           "global": False, "recursive": False, "loops": False}
 
     def visit(f: Function, stack: tuple) -> None:
         if f in stack:
             out["recursive"] = True
             return
+        if graph.natural_loops(f):
+            out["loops"] = True
         for i in f.instructions():
             op = i.op
             if op in _REFUSED_OPS or op in (Op.ATOMIC, Op.PRINT):
@@ -194,10 +222,11 @@ class _TraceCtx:
     Packed members: per-op counts, coalesced global line requests
     (``mem``), coalesced shared-tile line requests (``shm``), load/store
     instructions issued (``minst``), max two-sided IPDOM depth
-    (``maxd``), fuel spent, error bits."""
+    (``maxd``), fuel spent, error bits, and the loop counters
+    (``lp``, ``LOOP_KEYS`` order; empty for a kernel without loops)."""
 
     __slots__ = ("cnt_keys", "cnt", "mem", "shm", "minst", "maxd",
-                 "fuel", "err", "fuel_limit", "_live")
+                 "fuel", "err", "lp", "fuel_limit", "_live")
 
     def __init__(self, cnt_keys: tuple, fuel_limit: int, acc: tuple) -> None:
         self.cnt_keys = cnt_keys
@@ -214,6 +243,10 @@ class _TraceCtx:
         n = mask.any(axis=1).sum(dtype=jnp.int32)
         self._live[id(mask)] = (mask, n)
         return n
+
+    def knows_live(self, mask, n) -> None:
+        """``n`` is ``live(mask)``, already counted (a loop carries it)."""
+        self._live[id(mask)] = (mask, n)
 
     def charge(self, opval: str, mask) -> None:
         n = self.live(mask)
@@ -232,22 +265,34 @@ class _TraceCtx:
 
     def pack(self) -> tuple:
         return (tuple(self.cnt[k] for k in self.cnt_keys), self.mem,
-                self.shm, self.minst, self.maxd, self.fuel, self.err)
+                self.shm, self.minst, self.maxd, self.fuel, self.err,
+                tuple(self.lp))
 
     def unpack(self, t: tuple) -> None:
         cnt_t, self.mem, self.shm, self.minst, self.maxd, self.fuel, \
-            self.err = t
+            self.err, lp = t
         self.cnt = dict(zip(self.cnt_keys, cnt_t))
+        self.lp = list(lp)
         self._live = {}     # masks from another trace scope are stale
 
 
 _FUEL_IN_PACK = 5           # index of ``fuel`` in _TraceCtx.pack()
+_TRIPS, _PAID, _LIVE, _COMPACTIONS = range(len(LOOP_KEYS))
 
 
-def _zero_acc(n_ops: int) -> tuple:
+def _zero_acc(n_ops: int, n_loop: int) -> tuple:
     """The packed counters a launch starts from."""
     z = np.int32(0)
-    return ((z,) * n_ops,) + (z,) * 6
+    return ((z,) * n_ops,) + (z,) * 6 + ((z,) * n_loop,)
+
+
+def _ladder(rows: int) -> tuple:
+    """The row counts of a compacted loop's stages: ``rows``, then each
+    ``1 / _COMPACT_STEP`` of the last while that is a row or more."""
+    out = [rows]
+    while out[-1] // _COMPACT_STEP >= 1:
+        out.append(out[-1] // _COMPACT_STEP)
+    return tuple(out)
 
 
 class _State:
@@ -368,6 +413,7 @@ class _RowLowering:
         self.headers = {id(l.header): l for l in self.loops}
         self.pdom = graph.postdominators(fn)
         self.depth = 0                  # static enclosing-split count
+        self.loop_depth = 0             # loops whose body is being traced
         self.pending = None             # SPLIT awaiting its CBR
         self.ret_val = None
         # static cross-lane patterns shared by tile-store dedup
@@ -788,6 +834,96 @@ class _RowLowering:
         final.mask = st.mask
         return final, outside
 
+    def _compaction_plan(self, loop, term: Instr) -> dict | None:
+        """What a compacted ``vx_pred`` loop gathers beyond its carry, or
+        None where the loop keeps one stage: the registers, intrinsics,
+        scalar arguments and tokens its body reads from before the loop.
+        Compacted loops are outermost loops of the kernel's own body with
+        no call, no tile access and no buffer both loaded and stored in
+        the loop, whose exit test ``term`` is not launch-uniform.  The
+        rung's licence (private stores, no buffer both read and written)
+        already holds for the launch; the buffer rule restates it for the
+        loop, so an exited row's header registers are the same whichever
+        stage last computed them."""
+        if (self.facts is None or self.loop_depth
+                or len(_ladder(self.R)) < 2
+                or self._launch_uniform(term.operands[0])):
+            return None
+        defined, regs, intr, params, toks = set(), {}, set(), set(), set()
+        loaded, stored = set(), set()
+        for b in self.fn.blocks:
+            if not loop.contains(b):
+                continue
+            for i in b.instrs:
+                if i.result is not None:
+                    defined.add(id(i.result))
+                if i.op is Op.CALL:
+                    return None
+                if i.op in (Op.LOAD, Op.STORE):
+                    nm = self.buf_name(i.operands[0])
+                    if nm in self.tiles:
+                        return None
+                    (loaded if i.op is Op.LOAD else stored).add(nm)
+                if i.op is Op.INTR:
+                    intr.add((i.operands[0], i.operands[1]))
+                if i.op is Op.TMC_RESTORE:
+                    toks.add(id(i.operands[0]))
+                for o in i.operands:
+                    if isinstance(o, Reg):
+                        regs[id(o)] = o
+                    elif isinstance(o, Param) and o.ty is not Ty.PTR:
+                        params.add(id(o))
+        if loaded & stored:
+            return None
+        return {"regs": [r for r in regs if r not in defined
+                         and r in self.env],
+                "intr": [k for k in sorted(intr) if k in self.intr],
+                "params": [p for p in sorted(params)
+                           if not isinstance(self.argmap.get(p, ""), str)],
+                "tokens": [t for t in toks if t not in defined
+                           and t in self.tokens]}
+
+    def _launch_uniform(self, v: Value) -> bool:
+        """Whether ``v`` has one value in every lane of the launch:
+        constants, scalar arguments, the launch shape's intrinsics,
+        operations on such values, and loads of slots that are only ever
+        assigned such values.  A loop whose exit test is launch-uniform
+        makes the same trips in every row that enters it (unless a
+        branch on other values skips one of its slot stores), so
+        compaction would cost it without ever firing.  Only the choice
+        of lowering rests on this; both lowerings are exact."""
+        defs = {id(i.result): i for i in self.fn.instructions()
+                if i.result is not None}
+        stores: dict = {}
+        for i in self.fn.instructions():
+            if i.op is Op.SLOT_STORE:
+                stores.setdefault(id(i.operands[0]), []).append(
+                    i.operands[1])
+        seen: set = set()
+
+        def uniform(x) -> bool:
+            if isinstance(x, Const):
+                return True
+            if isinstance(x, Param):
+                return x.ty is not Ty.PTR
+            i = defs.get(id(x)) if isinstance(x, Reg) else None
+            if i is None:
+                return False
+            if i.op is Op.INTR:
+                return i.operands[0] in _LAUNCH_INTR
+            if i.op is Op.SLOT_LOAD:
+                sid = id(i.operands[0])
+                if sid in seen:     # assumed while its stores are checked
+                    return True
+                seen.add(sid)
+                return all(uniform(a) for a in stores.get(sid, ()))
+            if i.op in BINOPS or i.op in UNOPS or i.op in (Op.CMOV,
+                                                            Op.SELECT):
+                return all(uniform(a) for a in i.operands)
+            return False
+
+        return uniform(v)
+
     def _lower_loop(self, header, term: Instr, st: _State, loop,
                     inside, pred_mode: bool, negate: bool) -> _State:
         """Shared per-row loop lowering.  Called AT the header
@@ -798,6 +934,16 @@ class _RowLowering:
         ``lax.while_loop`` while any row stays live.  Count-exact per
         row: the visit where a row exits was charged under its
         then-live mask, and an exited row's mask is empty ever after.
+
+        The carry holds the count of live rows (rows with a live lane),
+        and the loop's test compares it with a threshold.  A
+        ``vx_pred`` loop with a ``_compaction_plan`` runs in stages on
+        ``_ladder`` row counts: stage ``k`` loops while more than the
+        next stage's rows are live, then gathers the live rows, in
+        order, into the next stage's rows (padding rows have no live
+        lane), and the stage's result is scattered back to the rows'
+        places.  Rows stay whole, and each row's trips stay where they
+        were, so counts, buffers and errors are those of one stage.
         """
         tc = self.tc
 
@@ -809,6 +955,9 @@ class _RowLowering:
                 self._uniform_err(s.mask, c)
             return c
 
+        def n_live(mask):
+            return mask.any(axis=1).sum(dtype=jnp.int32)
+
         tc.charge(term.op.value, st.mask)
         c0 = cond_val(st)
         st0 = st.copy()
@@ -816,11 +965,11 @@ class _RowLowering:
 
         slots, buf_names, hdr_regs, tok_ids = self._loop_carried(loop)
         slot_ids = sorted(slots, key=lambda sid: slots[sid].name)
-        snap_env = dict(self.env)
-        snap_tokens = dict(self.tokens)
-        zmask = jnp.zeros((self.R, self.W), dtype=jnp.bool_)
+        plan = self._compaction_plan(loop, term) if pred_mode else None
+        ladder = _ladder(self.R) if plan is not None else (self.R,)
 
         def pack_state(s: _State) -> tuple:
+            zmask = jnp.zeros((self.R, self.W), dtype=jnp.bool_)
             svals = []
             for sid in slot_ids:
                 a = s.slots.get(sid)
@@ -834,48 +983,146 @@ class _RowLowering:
                     tuple(self.tokens.get(t, zmask) for t in tok_ids),
                     s.mask, tc.pack())
 
-        def unpack_state(carry) -> _State:
-            svals, bvals, rvals, tvals, mask, tcp = carry
-            s = st0.copy()
+        def unpack_state(carry, base: _State, env: dict,
+                         tokens: dict) -> _State:
+            svals, bvals, rvals, tvals, mask, tcp = carry[:6]
+            s = base.copy()
             for sid, a in zip(slot_ids, svals):
                 s.slots[sid] = a
             for nm, a in zip(buf_names, bvals):
                 s.bufs[nm] = a
-            self.env = dict(snap_env)
+            self.env = dict(env)
             for r, a in zip(hdr_regs, rvals):
                 self.env[id(r)] = a
-            self.tokens = dict(snap_tokens)
+            self.tokens = dict(tokens)
             for t, a in zip(tok_ids, tvals):
                 self.tokens[t] = a
             s.mask = mask
             tc.unpack(tcp)
             return s
 
-        def cond_fn(carry):
-            return carry[4].any() & (
-                carry[5][_FUEL_IN_PACK] < jnp.int32(tc.fuel_limit))
+        def stage(k: int, carry: tuple, base: _State, env: dict,
+                  tokens: dict) -> tuple:
+            """Stage ``k`` of the ladder, and the stages after it."""
+            rows = ladder[k]
+            floor = ladder[k + 1] if k + 1 < len(ladder) else 0
 
-        def body_fn(carry):
-            s = unpack_state(carry)
-            kind, _, s = self.walk(inside, 0, s, header)
-            if kind != "stop":
-                raise LowerError("loop body escaped its header")
-            # the next counted header visit (the back-edge BR was
-            # charged by the walk)
-            for hi in header.instrs[:-1]:
-                if hi.op in (Op.SPLIT, Op.CBR, Op.PRED, Op.BR, Op.RET,
-                             Op.JOIN):
-                    raise LowerError("control op in loop-header prefix")
-                s = self._lower_straight(hi, s)
-            tc.charge(term.op.value, s.mask)
-            c = cond_val(s)
-            s = s.copy()
-            s.mask = s.mask & c
-            return pack_state(s)
+            def cond_fn(c):
+                return (c[6] > floor) & (
+                    c[5][_FUEL_IN_PACK] < jnp.int32(tc.fuel_limit))
 
-        out = jax.lax.while_loop(cond_fn, body_fn, pack_state(st0))
-        final = unpack_state(out)
-        return final
+            def body_fn(c):
+                s = unpack_state(c, base, env, tokens)
+                tc.knows_live(s.mask, c[6])
+                tc.lp[_TRIPS] = tc.lp[_TRIPS] + 1
+                tc.lp[_LIVE] = tc.lp[_LIVE] + c[6]
+                self.loop_depth += 1
+                kind, _, s = self.walk(inside, 0, s, header)
+                self.loop_depth -= 1
+                if kind != "stop":
+                    raise LowerError("loop body escaped its header")
+                # the next counted header visit (the back-edge BR was
+                # charged by the walk)
+                for hi in header.instrs[:-1]:
+                    if hi.op in (Op.SPLIT, Op.CBR, Op.PRED, Op.BR, Op.RET,
+                                 Op.JOIN):
+                        raise LowerError("control op in loop-header prefix")
+                    s = self._lower_straight(hi, s)
+                tc.charge(term.op.value, s.mask)
+                c = cond_val(s)
+                s = s.copy()
+                s.mask = s.mask & c
+                return pack_state(s) + (n_live(s.mask),)
+
+            trips = carry[5][-1][_TRIPS]
+            out = jax.lax.while_loop(cond_fn, body_fn, carry)
+            tc.unpack(out[5])
+            tc.lp[_PAID] = tc.lp[_PAID] + jnp.int32(rows) * (
+                tc.lp[_TRIPS] - trips)
+            out = out[:5] + (tc.pack(), out[6])
+            if not floor:
+                return out
+
+            def compact(c):
+                svals, bvals, rvals, tvals, mask, tcp, n = c
+                idx = jnp.nonzero(mask.any(axis=1), size=floor,
+                                  fill_value=rows)[0]
+                carried = (*svals, *rvals, *tvals, mask)
+                picked = iter(_take_rows(
+                    carried + tuple(env[r] for r in plan["regs"])
+                    + tuple(tokens[t] for t in plan["tokens"])
+                    + tuple(self.intr[key] for key in plan["intr"])
+                    + tuple(self.argmap[p] for p in plan["params"]), idx))
+
+                def nxt(keys):
+                    return {key: next(picked) for key in keys}
+
+                tc.unpack(tcp)
+                tc.lp[_COMPACTIONS] = tc.lp[_COMPACTIONS] + 1
+                sub = (tuple(next(picked) for _ in svals), bvals,
+                       tuple(next(picked) for _ in rvals),
+                       tuple(next(picked) for _ in tvals),
+                       next(picked), tc.pack(), n)
+                sub_env, sub_tokens = nxt(plan["regs"]), nxt(plan["tokens"])
+                with self._narrowed(floor, nxt(plan["intr"]),
+                                    nxt(plan["params"])):
+                    got = stage(k + 1, sub, _State({}, base.bufs, None),
+                                sub_env, sub_tokens)
+                back = iter(_put_rows(carried,
+                                      (*got[0], *got[2], *got[3], got[4]),
+                                      idx))
+                return (tuple(next(back) for _ in svals), got[1],
+                        tuple(next(back) for _ in rvals),
+                        tuple(next(back) for _ in tvals),
+                        next(back), got[5], got[6])
+
+            return jax.lax.cond(out[6] > 0, compact, lambda c: c, out)
+
+        snap_env, snap_tokens = dict(self.env), dict(self.tokens)
+        out = stage(0, pack_state(st0) + (n_live(st0.mask),), st0,
+                    snap_env, snap_tokens)
+        return unpack_state(out, st0, snap_env, snap_tokens)
+
+    @contextmanager
+    def _narrowed(self, rows: int, intr: dict, params: dict):
+        """Trace on ``rows`` rows: the row count, and the intrinsics and
+        scalar arguments a compacted loop reads, already gathered."""
+        saved = self.R, self.intr, self.argmap
+        self.R = rows
+        self.intr = intr
+        self.argmap = {**self.argmap, **params}
+        try:
+            yield
+        finally:
+            self.R, self.intr, self.argmap = saved
+
+
+def _take_rows(arrs: tuple, idx) -> list:
+    """The rows ``idx`` of each (R, W) array, gathered at once; an index
+    past the last row reads zeros (False)."""
+    got = jnp.take(_stack_rows(arrs), idx, axis=1, mode="fill",
+                   fill_value=0)
+    return _unstack_rows(got, arrs)
+
+
+def _put_rows(arrs: tuple, subs: tuple, idx) -> list:
+    """``arrs`` with rows ``idx`` replaced by the rows of ``subs``,
+    scattered at once; an index past the last row is dropped."""
+    got = _stack_rows(arrs).at[:, idx].set(_stack_rows(subs), mode="drop")
+    return _unstack_rows(got, arrs)
+
+
+def _stack_rows(arrs: tuple):
+    """(R, W) arrays of any of the rung's dtypes as one (n, R, W) int32."""
+    return jnp.stack([jax.lax.bitcast_convert_type(a, jnp.int32)
+                      if a.dtype == jnp.float32 else a.astype(jnp.int32)
+                      for a in arrs])
+
+
+def _unstack_rows(stacked, like: tuple) -> list:
+    return [jax.lax.bitcast_convert_type(stacked[j], jnp.float32)
+            if a.dtype == jnp.float32 else stacked[j].astype(a.dtype)
+            for j, a in enumerate(like)]
 
 
 # --------------------------------------------------------------------------
@@ -911,7 +1158,7 @@ class _Compiled:
     surface before anything runs)."""
 
     __slots__ = ("sig", "program", "jitted", "abstract", "lowered", "tiers",
-                 "cnt_keys", "buf_names", "scalar_names", "scalar_dtypes",
+                 "cnt_keys", "loop_keys", "buf_names", "scalar_names", "scalar_dtypes",
                  "cw", "trace_s", "compile_s", "cert_s")
 
     def executable(self, tier: str):
@@ -1051,6 +1298,7 @@ def _trace(fn: Function, params, buffers: dict, scalar_args: dict,
     ops: set = set()
     _collect_ops(fn, ops, set())
     cnt_keys = tuple(sorted(ops))
+    loop_keys = LOOP_KEYS if _scan_fn(fn)["loops"] else ()
     fuel_limit = int(params.fuel)
     n_wg = params.grid * params.grid_y
 
@@ -1136,10 +1384,11 @@ def _trace(fn: Function, params, buffers: dict, scalar_args: dict,
         tuple(jax.ShapeDtypeStruct((), np.dtype(scalar_dtypes[nm]))
               for nm in scalar_names if nm in scalar_dtypes),
         i32, i32,
-        ((i32,) * len(cnt_keys),) + (i32,) * 6)
+        ((i32,) * len(cnt_keys),) + (i32,) * 6 + ((i32,) * len(loop_keys),))
     rec.lowered = None
     rec.tiers = {}
     rec.cnt_keys = cnt_keys
+    rec.loop_keys = loop_keys
     rec.buf_names = buf_names
     rec.scalar_names = tuple(nm for nm in scalar_names
                              if nm in scalar_dtypes)
@@ -1207,7 +1456,8 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
          scalar_args: dict, params, tier: str = "fast") -> tuple:
     """Run every chunk on the given executable tier; returns
     (host_bufs, jstats dict), ``jstats["dispatches"]`` the executable
-    calls made.  One call runs the whole grid; while a deadline is armed
+    calls made, ``jstats["loops"]`` the loop counters (``LOOP_KEYS``;
+    empty for a kernel without loops).  One call runs the whole grid; while a deadline is armed
     the same executable is stepped a chunk at a time, with the deadline
     checked before each call.  Site ``jax.exec`` is checked before each
     call and once after the last.  Never mutates ``buffers`` — results
@@ -1224,7 +1474,7 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
     # executable and the oracle all agree bit-for-bit
     run = (rec.program if jax.config.jax_disable_jit
            else rec.executable(tier))
-    acc = _zero_acc(len(rec.cnt_keys))
+    acc = _zero_acc(len(rec.cnt_keys), len(rec.loop_keys))
     step = rec.cw if _gov.ACTIVE else n_wg
     calls = 0
     with span("volt.jax.dispatch"):
@@ -1239,7 +1489,8 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
         if _faults.ACTIVE:
             _faults.maybe_fault("jax.exec")
     with span("volt.jax.sync"):
-        cnt, mem_, shm, minst, maxd, _fuel, err = jax.device_get(acc)
+        cnt, mem_, shm, minst, maxd, _fuel, err, loops = \
+            jax.device_get(acc)
     err_v = int(err)
     if err_v:
         names = [nm for bit, nm in ((ERR_OOB_STORE, "oob-store"),
@@ -1263,6 +1514,7 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
         "shared_requests": int(shm),
         "max_ipdom_depth": int(maxd),
         "dispatches": calls,
+        "loops": {k: int(v) for k, v in zip(rec.loop_keys, loops)},
     }
     return host_bufs, jstats
 
@@ -1617,6 +1869,8 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
         _apply(host_bufs, jstats, buffers, stats)
     JAX_TELEMETRY["engaged"] += 1
     JAX_TELEMETRY["dispatches"] += jstats["dispatches"]
+    for k, v in jstats["loops"].items():
+        JAX_TELEMETRY[k] += v
     JAX_TELEMETRY["upload_bytes"] += sum(buffers[nm].nbytes
                                          for nm in rec.buf_names)
     JAX_TELEMETRY["download_bytes"] += sum(a.nbytes

@@ -1,7 +1,9 @@
 """The jax rung's chunk programs compile for a TPU v5e chip.
 
 For each kernel of ``suite.USER_SIZES`` (the sizes ``chip_smoke.py``
-launches on the chip) and each executable tier, the chunk program that
+launches on the chip), the benchmark's Kronecker SpMV at its scale
+(``chipbench/configs/spmv_kron*``, its ragged loop compacted), and each
+executable tier, the chunk program that
 ``jaxgen`` traces for that launch is compiled for device 0 of a
 described ``v5e:2x2`` topology — no chip attached, nothing runs.  What
 the TPU compiler refuses here fails here, not on the chip.
@@ -10,7 +12,10 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
 file.
 """
+import importlib.util
+import json
 import os
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -20,7 +25,8 @@ from repro.core.backends import jaxgen
 from repro.core.runtime import compile_kernel
 from repro.volt_bench.suite import BENCHES, USER_SIZES
 
-KERNELS = tuple(USER_SIZES)
+CONFIGS = Path(__file__).resolve().parents[1] / "chipbench" / "configs"
+KERNELS = tuple(USER_SIZES) + ("spmv_kron",)
 TIERS = tuple(jaxgen._TIER_OPTIONS)
 
 
@@ -54,10 +60,13 @@ def lowered(one_chip):
 
     def get(name):
         if name not in done:
-            b = BENCHES[name]
-            bufs, scalars, params = b.make(np.random.default_rng(0),
-                                           **USER_SIZES[name])
-            fn = compile_kernel(b.handle).fn
+            if name == "spmv_kron":
+                fn, bufs, scalars, params = _spmv_kron()
+            else:
+                b = BENCHES[name]
+                bufs, scalars, params = b.make(np.random.default_rng(0),
+                                               **USER_SIZES[name])
+                fn = compile_kernel(b.handle).fn
             rec = jaxgen._trace(fn, params, bufs, scalars,
                                 jaxgen._chunk_width(params))
             args = jax.tree.map(
@@ -70,6 +79,28 @@ def lowered(one_chip):
     yield get
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+def _load(path: Path):
+    spec = importlib.util.spec_from_file_location(
+        f"test_tpu_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _spmv_kron():
+    """The benchmark configuration's kernel and inputs at its size."""
+    from repro.core.interp import LaunchParams
+    cfg = json.loads((CONFIGS / "spmv_kron.json").read_text())
+    with pytest.MonkeyPatch.context() as mp:
+        mp.syspath_prepend(str(CONFIGS.parents[1]))
+        ref = _load(CONFIGS / "spmv_kron_ref.py")
+        kernel = _load(CONFIGS / "spmv_kron_kernel.py").KERNEL
+    bufs, scalars, grid = ref.make(np.random.default_rng(0), cfg["size"])
+    params = LaunchParams(grid=grid, local_size=cfg["block"], warp_size=32,
+                          fuel=cfg["fuel"])
+    return compile_kernel(kernel).fn, bufs, scalars, params
 
 
 @pytest.mark.parametrize("tier", TIERS)
