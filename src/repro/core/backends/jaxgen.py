@@ -28,8 +28,13 @@ row layout:
     body is not paid for on rows that have left the loop;
   * lockstep barriers are no-ops (the rung only licenses barriers at
     ``n_warps == 1``, where a row IS the whole workgroup);
-  * loads/stores lower to gathers/scatters; store injectivity comes
-    from ``passes.analysis.export_codegen_facts`` (the same
+  * loads/stores of private tiles lower to per-row gathers/scatters;
+    global loads/stores whose index the middle end classed warp-affine
+    (``passes.analysis.window_classes``) are served as a slice of the
+    chunk, a window a row or an element a row broadcast, wherever a
+    device check confirms the form on every lane, and as element
+    gathers/scatters otherwise; store injectivity comes from
+    ``passes.analysis.export_codegen_facts`` (the same
     ``affine_mem_facts`` privacy classes that license run-ahead).
 
 ``ExecStats`` are not sampled — they are *computed in the trace*, to
@@ -76,7 +81,7 @@ from .. import faults as _faults
 from .. import governor as _gov
 from .. import interp as _interp
 from ..interp_mem import CACHE_LINE_ELEMS
-from ..passes.analysis import export_codegen_facts
+from ..passes.analysis import export_codegen_facts, launch_uniform
 from ..spans import span
 
 _TY_DTYPE = {Ty.I32: jnp.int32, Ty.F32: jnp.float32, Ty.BOOL: jnp.bool_}
@@ -93,13 +98,21 @@ _CHUNK_WGS = 256
 _COMPACT_STEP = 4
 
 #: the layout of the compiled program, part of every shape signature:
-#: the chunk loop runs inside one executable.  A verdict certified for
-#: another layout (one executable call per chunk) never promotes this one
-_PROGRAM = "device-chunk-loop"
+#: the chunk loop runs inside one executable, and warp-affine global
+#: accesses are served as row windows under a device check.  A verdict
+#: certified for another layout (one executable call per chunk, or
+#: element gathers only) never promotes this one
+_PROGRAM = "device-chunk-loop, row windows"
 
-#: intrinsics with one value in every lane of a launch (its shape)
-_LAUNCH_INTR = {"local_size", "num_groups", "global_size", "num_threads",
-                "num_warps", "grid_dim"}
+#: the width of the rows a global buffer is viewed as when each row of
+#: a chunk reads its own window: one (8, 128) tile of the chip is 1024
+#: consecutive elements, so the view is a bitcast and the row gather
+#: moves whole tile rows
+_VIEW = 128
+
+#: the name scope of every element gather and scatter of a global
+#: buffer, in the lowered program's op metadata
+_GATHER_SCOPE = "volt_element_gather"
 
 #: sorts-after-everything sentinel for masked-out line keys (valid line
 #: keys are element_index // CACHE_LINE_ELEMS <= 2**27)
@@ -146,6 +159,9 @@ JAX_TELEMETRY = {
     "loop_rows_paid": 0, # rows the loop body was computed for
     "loop_rows_live": 0, # of those, rows with a live lane
     "loop_compactions": 0,  # live rows gathered into a narrower stage
+    # global-buffer accesses executed, per chunk and loop trip:
+    "mem_window": 0,     # served by a window, broadcast or update slice
+    "mem_gather": 0,     # served by an element gather or scatter
 }
 
 #: the loop counters of the counter pack, in pack order (the pack holds
@@ -222,11 +238,14 @@ class _TraceCtx:
     Packed members: per-op counts, coalesced global line requests
     (``mem``), coalesced shared-tile line requests (``shm``), load/store
     instructions issued (``minst``), max two-sided IPDOM depth
-    (``maxd``), fuel spent, error bits, and the loop counters
-    (``lp``, ``LOOP_KEYS`` order; empty for a kernel without loops)."""
+    (``maxd``), fuel spent, error bits, global-buffer accesses served
+    by a window form (``mwin``) and by an element gather or scatter
+    (``mgat``), and the loop counters (``lp``, ``LOOP_KEYS`` order;
+    empty for a kernel without loops)."""
 
     __slots__ = ("cnt_keys", "cnt", "mem", "shm", "minst", "maxd",
-                 "fuel", "err", "lp", "fuel_limit", "_live")
+                 "fuel", "err", "mwin", "mgat", "lp", "fuel_limit",
+                 "_live")
 
     def __init__(self, cnt_keys: tuple, fuel_limit: int, acc: tuple) -> None:
         self.cnt_keys = cnt_keys
@@ -266,24 +285,25 @@ class _TraceCtx:
     def pack(self) -> tuple:
         return (tuple(self.cnt[k] for k in self.cnt_keys), self.mem,
                 self.shm, self.minst, self.maxd, self.fuel, self.err,
-                tuple(self.lp))
+                self.mwin, self.mgat, tuple(self.lp))
 
     def unpack(self, t: tuple) -> None:
         cnt_t, self.mem, self.shm, self.minst, self.maxd, self.fuel, \
-            self.err, lp = t
+            self.err, self.mwin, self.mgat, lp = t
         self.cnt = dict(zip(self.cnt_keys, cnt_t))
         self.lp = list(lp)
         self._live = {}     # masks from another trace scope are stale
 
 
 _FUEL_IN_PACK = 5           # index of ``fuel`` in _TraceCtx.pack()
+_N_SCALARS = 8              # scalars between the op counts and ``lp``
 _TRIPS, _PAID, _LIVE, _COMPACTIONS = range(len(LOOP_KEYS))
 
 
 def _zero_acc(n_ops: int, n_loop: int) -> tuple:
     """The packed counters a launch starts from."""
     z = np.int32(0)
-    return ((z,) * n_ops,) + (z,) * 6 + ((z,) * n_loop,)
+    return ((z,) * n_ops,) + (z,) * _N_SCALARS + ((z,) * n_loop,)
 
 
 def _ladder(rows: int) -> tuple:
@@ -575,6 +595,88 @@ class _RowLowering:
     def _count_lines(self, clip, mask):
         return count_lines_traced(clip, mask, self.W)
 
+    def _window_class(self, i: Instr, nm: str) -> str | None:
+        """The middle end's window candidate class of global access
+        ``i`` ("window", "row_uniform"), or None: tiles, callees and
+        accesses whose index did not classify."""
+        if self.facts is None or nm in self.tiles:
+            return None
+        return self.facts["window"].get(self.iidx[id(i)])
+
+    def _run_ok(self, ix, n: int):
+        """``ix`` is one run ``ix[0, 0] + W * row + lane`` in bounds."""
+        R, W = self.R, self.W
+        x0 = ix[0, 0]
+        flat = jnp.arange(R * W, dtype=jnp.int32).reshape(R, W)
+        return ((x0 >= 0) & (x0 <= n - R * W) & (ix == x0 + flat).all())
+
+    def _served(self, forms: list, fallback):
+        """The value of the first of ``forms`` (``(check, thunk)``) whose
+        check holds on the device, else of ``fallback``, the element
+        gather or scatter; one ``lax.switch``.  Counts the access in
+        ``mwin`` or ``mgat``."""
+        tc = self.tc
+        if not forms:
+            tc.mgat = tc.mgat + 1
+            return fallback()
+        k = jnp.int32(len(forms))
+        for j in reversed(range(len(forms))):
+            k = jnp.where(forms[j][0], jnp.int32(j), k)
+        hit = (k < len(forms)).astype(jnp.int32)
+        tc.mwin = tc.mwin + hit
+        tc.mgat = tc.mgat + 1 - hit
+        return jax.lax.switch(k, [f for _, f in forms] + [fallback])
+
+    def _row_windows(self, buf, base):
+        """Row ``r`` of the result is ``buf[base[r]:base[r] + W]``, each
+        ``base[r]`` a multiple of W: a gather of the ``_VIEW``-wide rows
+        that hold the windows, then each row's window picked out of its
+        ``_VIEW // W`` places."""
+        W = self.W
+        wide = buf.reshape(-1, _VIEW).at[base // _VIEW].get(
+            mode="promise_in_bounds")
+        at = (base % _VIEW) // W
+        out = wide[:, :W]
+        for j in range(1, _VIEW // W):
+            out = jnp.where((at == j)[:, None], wide[:, j * W:(j + 1) * W],
+                            out)
+        return out
+
+    def _global_load(self, i: Instr, nm: str, buf, ix, clip):
+        """``buf[clip]``, served as windows where the index is a
+        candidate and the device check confirms its form on every lane,
+        active or not, so every form reads exactly what the gather would:
+        one slice of the whole chunk (a run), one window of W a row
+        (each starting at a multiple of W), or one element a row,
+        broadcast along its lanes."""
+        R, W = self.R, self.W
+        n = buf.shape[0]
+        cls = self._window_class(i, nm)
+        forms = []
+        base = ix[:, 0] if cls else None
+        if cls == "window":
+            if R * W <= n:
+                forms.append((self._run_ok(ix, n), lambda: jax.lax.
+                              dynamic_slice(buf, (ix[0, 0],), (R * W,))
+                              .reshape(R, W)))
+            if n % _VIEW == 0 and _VIEW % W == 0:
+                ok = ((base >= 0) & (base <= n - W) & (base % W == 0)
+                      ).all() & (ix == base[:, None] + jnp.arange(
+                          W, dtype=jnp.int32)).all()
+                forms.append((ok, lambda: self._row_windows(buf, base)))
+        elif cls == "row_uniform":
+            ok = ((base >= 0) & (base < n)).all() \
+                & (ix == base[:, None]).all()
+            forms.append((ok, lambda: jnp.broadcast_to(
+                buf.at[base].get(mode="promise_in_bounds")[:, None],
+                (R, W))))
+
+        def gather():
+            with jax.named_scope(_GATHER_SCOPE):
+                return buf[clip]
+
+        return self._served(forms, gather)
+
     def _lower_load(self, i: Instr, st: _State) -> _State:
         nm = self.buf_name(i.operands[0])
         buf = st.bufs.get(nm)
@@ -588,7 +690,7 @@ class _RowLowering:
         if nm in self.tiles:
             v = jnp.take_along_axis(buf, clip, axis=1)
         else:
-            v = buf[clip]
+            v = self._global_load(i, nm, buf, ix, clip)
         tc.minst = tc.minst + tc.live(st.mask)
         self.env[id(i.result)] = v
         return st
@@ -634,9 +736,26 @@ class _RowLowering:
             if not (priv == "2d" or (priv == "1d" and self.shape_1d)):
                 raise LowerError("store not proven injective at this "
                                  "launch shape")
-            safe = jnp.where(wm, clip, jnp.int32(n))
-            st.bufs[nm] = buf.at[safe.reshape(-1)].set(
-                vv.reshape(-1), mode="drop")
+            forms = []
+            if self._window_class(i, nm) == "window" and \
+                    self.R * self.W <= n:
+                # the whole chunk is one run: its lanes not written keep
+                # the buffer's values, as the scatter leaves them
+                def put():
+                    x0 = ix[0, 0]
+                    old = jax.lax.dynamic_slice(
+                        buf, (x0,), (self.R * self.W,)).reshape(ix.shape)
+                    return jax.lax.dynamic_update_slice(
+                        buf, jnp.where(wm, vv, old).reshape(-1), (x0,))
+                forms.append((self._run_ok(ix, n), put))
+
+            def scatter():
+                safe = jnp.where(wm, clip, jnp.int32(n))
+                with jax.named_scope(_GATHER_SCOPE):
+                    return buf.at[safe.reshape(-1)].set(
+                        vv.reshape(-1), mode="drop")
+
+            st.bufs[nm] = self._served(forms, scatter)
         return st
 
     # -- collectives -------------------------------------------------------
@@ -840,14 +959,19 @@ class _RowLowering:
         scalar arguments and tokens its body reads from before the loop.
         Compacted loops are outermost loops of the kernel's own body with
         no call, no tile access and no buffer both loaded and stored in
-        the loop, whose exit test ``term`` is not launch-uniform.  The
+        the loop, whose exit test ``term`` is not launch-uniform
+        (``analysis.launch_uniform``): such a loop makes the same trips
+        in every row that enters it (unless a branch on other values
+        skips one of its slot stores), so compaction would cost it
+        without ever firing.  Only the choice of lowering rests on this;
+        both lowerings are exact.  The
         rung's licence (private stores, no buffer both read and written)
         already holds for the launch; the buffer rule restates it for the
         loop, so an exited row's header registers are the same whichever
         stage last computed them."""
         if (self.facts is None or self.loop_depth
                 or len(_ladder(self.R)) < 2
-                or self._launch_uniform(term.operands[0])):
+                or launch_uniform(self.fn, term.operands[0])):
             return None
         defined, regs, intr, params, toks = set(), {}, set(), set(), set()
         loaded, stored = set(), set()
@@ -882,47 +1006,6 @@ class _RowLowering:
                            if not isinstance(self.argmap.get(p, ""), str)],
                 "tokens": [t for t in toks if t not in defined
                            and t in self.tokens]}
-
-    def _launch_uniform(self, v: Value) -> bool:
-        """Whether ``v`` has one value in every lane of the launch:
-        constants, scalar arguments, the launch shape's intrinsics,
-        operations on such values, and loads of slots that are only ever
-        assigned such values.  A loop whose exit test is launch-uniform
-        makes the same trips in every row that enters it (unless a
-        branch on other values skips one of its slot stores), so
-        compaction would cost it without ever firing.  Only the choice
-        of lowering rests on this; both lowerings are exact."""
-        defs = {id(i.result): i for i in self.fn.instructions()
-                if i.result is not None}
-        stores: dict = {}
-        for i in self.fn.instructions():
-            if i.op is Op.SLOT_STORE:
-                stores.setdefault(id(i.operands[0]), []).append(
-                    i.operands[1])
-        seen: set = set()
-
-        def uniform(x) -> bool:
-            if isinstance(x, Const):
-                return True
-            if isinstance(x, Param):
-                return x.ty is not Ty.PTR
-            i = defs.get(id(x)) if isinstance(x, Reg) else None
-            if i is None:
-                return False
-            if i.op is Op.INTR:
-                return i.operands[0] in _LAUNCH_INTR
-            if i.op is Op.SLOT_LOAD:
-                sid = id(i.operands[0])
-                if sid in seen:     # assumed while its stores are checked
-                    return True
-                seen.add(sid)
-                return all(uniform(a) for a in stores.get(sid, ()))
-            if i.op in BINOPS or i.op in UNOPS or i.op in (Op.CMOV,
-                                                            Op.SELECT):
-                return all(uniform(a) for a in i.operands)
-            return False
-
-        return uniform(v)
 
     def _lower_loop(self, header, term: Instr, st: _State, loop,
                     inside, pred_mode: bool, negate: bool) -> _State:
@@ -1384,7 +1467,8 @@ def _trace(fn: Function, params, buffers: dict, scalar_args: dict,
         tuple(jax.ShapeDtypeStruct((), np.dtype(scalar_dtypes[nm]))
               for nm in scalar_names if nm in scalar_dtypes),
         i32, i32,
-        ((i32,) * len(cnt_keys),) + (i32,) * 6 + ((i32,) * len(loop_keys),))
+        ((i32,) * len(cnt_keys),) + (i32,) * _N_SCALARS
+        + ((i32,) * len(loop_keys),))
     rec.lowered = None
     rec.tiers = {}
     rec.cnt_keys = cnt_keys
@@ -1489,7 +1573,7 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
         if _faults.ACTIVE:
             _faults.maybe_fault("jax.exec")
     with span("volt.jax.sync"):
-        cnt, mem_, shm, minst, maxd, _fuel, err, loops = \
+        cnt, mem_, shm, minst, maxd, _fuel, err, mwin, mgat, loops = \
             jax.device_get(acc)
     err_v = int(err)
     if err_v:
@@ -1515,6 +1599,7 @@ def _run(rec: _Compiled, fn: Function, buffers: dict,
         "max_ipdom_depth": int(maxd),
         "dispatches": calls,
         "loops": {k: int(v) for k, v in zip(rec.loop_keys, loops)},
+        "windows": {"mem_window": int(mwin), "mem_gather": int(mgat)},
     }
     return host_bufs, jstats
 
@@ -1869,7 +1954,7 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
         _apply(host_bufs, jstats, buffers, stats)
     JAX_TELEMETRY["engaged"] += 1
     JAX_TELEMETRY["dispatches"] += jstats["dispatches"]
-    for k, v in jstats["loops"].items():
+    for k, v in (*jstats["loops"].items(), *jstats["windows"].items()):
         JAX_TELEMETRY[k] += v
     JAX_TELEMETRY["upload_bytes"] += sum(buffers[nm].nbytes
                                          for nm in rec.buf_names)

@@ -28,7 +28,8 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from ..interp_mem import AffineFact
-from ..vir import Const, Function, Op, Param, Reg, Ty, Value
+from ..vir import (BINOPS, Const, Function, Op, Param, Reg, Ty, UNOPS,
+                   Value)
 from .. import graph
 
 
@@ -132,7 +133,9 @@ class AnalysisManager:
 # --------------------------------------------------------------------------
 # Affine index facts — decode-time classification of memory-access index
 # vectors, shared by the interpreter's coalescing engine (core/interp_mem)
-# and the grid batcher's store-privacy licence (core/interp).
+# and the grid batcher's store-privacy licence (core/interp); with three
+# more rules that a device check must confirm, also the jax rung's window
+# candidates (``window_classes``).
 #
 # Every index chain is resolved to a LINEAR FORM over the SIMT id basis
 #
@@ -257,23 +260,77 @@ def _is_uniform_product(v: Value, defs, slot_stores, entry_ids,
     return False
 
 
-def affine_mem_facts(fn: Function) -> _MemFacts:
-    """Classify every LOAD/STORE/ATOMIC index of ``fn`` (memoized on the
-    function, keyed by its ir_version)."""
-    cached = getattr(fn, "_mem_facts", None)
-    if cached is not None and cached[0] == fn.ir_version:
-        return cached[1]
-
+def _def_maps(fn: Function) -> Tuple[Dict[int, Any], Dict[int, list]]:
+    """(id(result reg) -> defining instr, id(slot) -> its SLOT_STOREs)."""
     defs: Dict[int, Any] = {}
     slot_stores: Dict[int, list] = {}
-    entry_ids = {id(i) for i in fn.entry.instrs}
     for i in fn.instructions():
         if i.result is not None:
             defs[id(i.result)] = i
         if i.op is Op.SLOT_STORE:
             slot_stores.setdefault(id(i.operands[0]), []).append(i)
+    return defs, slot_stores
 
-    def classify(v: Value, depth: int) -> Optional[_Lin]:
+
+def launch_uniform(fn: Function, v: Value, maps=None) -> bool:
+    """Whether ``v`` has one value in every lane of the launch:
+    constants, scalar arguments, the launch shape's intrinsics,
+    operations on such values, and loads of slots that are only ever
+    assigned such values (a counted loop's counter).  ``maps`` is
+    ``_def_maps(fn)``, when the caller already has it."""
+    defs, stores = maps or _def_maps(fn)
+    seen: set = set()
+
+    def uniform(x) -> bool:
+        if isinstance(x, Const):
+            return True
+        if isinstance(x, Param):
+            return x.ty is not Ty.PTR
+        i = defs.get(id(x)) if isinstance(x, Reg) else None
+        if i is None:
+            return False
+        if i.op is Op.INTR:
+            return i.operands[0] in _LAUNCH_UNIFORM_INTRS
+        if i.op is Op.SLOT_LOAD:
+            sid = id(i.operands[0])
+            if sid in seen:     # assumed while its stores are checked
+                return True
+            seen.add(sid)
+            return all(uniform(s.operands[1]) for s in stores.get(sid, ()))
+        if i.op in BINOPS or i.op in UNOPS or i.op in (Op.CMOV, Op.SELECT):
+            return all(uniform(a) for a in i.operands)
+        return False
+
+    return uniform(v)
+
+
+class _Classifier:
+    """Resolves index chains of one function to ``_Lin`` forms.
+
+    ``wide=False`` gives the facts the interpreter relies on without a
+    check.  ``wide=True`` adds three rules that hold only on launches a
+    device check confirms, for the jax rung's window candidates: a slot
+    with one store that dominates the load reads that store's value; a
+    slot only ever assigned launch-uniform values is a uniform addend;
+    ``x // u`` with ``x`` of lane stride 0 or 1 and ``u`` uniform is a
+    per-row term (for stride 1, when ``u`` is a multiple of the warp
+    width and each row's window starts at one), so ``x - (x // u) * u``
+    is ``x`` less that term."""
+
+    def __init__(self, fn: Function, wide: bool) -> None:
+        self.fn = fn
+        self.wide = wide
+        self.maps = _def_maps(fn)
+        self.entry_ids = {id(i) for i in fn.entry.instrs}
+        self.dom = graph.dominators(fn) if wide else None
+        self.where = ({id(i): (b, k) for b in fn.blocks
+                       for k, i in enumerate(b.instrs)} if wide else None)
+
+    def _dominates(self, s, i) -> bool:
+        (sb, sk), (ib, ik) = self.where[id(s)], self.where[id(i)]
+        return sk < ik if sb is ib else self.dom.dominates(sb, ib)
+
+    def lin(self, v: Value, depth: int = 0) -> Optional[_Lin]:
         if depth > 12:
             return None
         if isinstance(v, Const):
@@ -288,6 +345,7 @@ def affine_mem_facts(fn: Function) -> _MemFacts:
             return _Lin(has_scalar=True)     # launch scalar: uniform
         if not isinstance(v, Reg):
             return None
+        defs, slot_stores = self.maps
         i = defs.get(id(v))
         if i is None:
             return None
@@ -303,20 +361,31 @@ def affine_mem_facts(fn: Function) -> _MemFacts:
             return None
         if op is Op.SLOT_LOAD:
             ss = slot_stores.get(id(i.operands[0]), [])
-            # exactly one store, in the entry block: it dominates every
-            # load, so the load can never observe the slot's zero init
-            if len(ss) != 1 or id(ss[0]) not in entry_ids:
-                return None
-            return classify(ss[0].operands[1], depth + 1)
+            # exactly one store, in the entry block (wide: or one that
+            # dominates this load): the load can never observe the slot's
+            # zero init
+            if len(ss) == 1 and (id(ss[0]) in self.entry_ids or (
+                    self.wide and self._dominates(ss[0], i))):
+                return self.lin(ss[0].operands[1], depth + 1)
+            if self.wide and launch_uniform(self.fn, v, self.maps):
+                return _Lin(has_scalar=True)
+            return None
         if op in (Op.ADD, Op.SUB):
-            a = classify(i.operands[0], depth + 1)
-            b = classify(i.operands[1], depth + 1)
+            a = self.lin(i.operands[0], depth + 1)
+            b = self.lin(i.operands[1], depth + 1)
             if a is None or b is None:
                 return None
             return _lin_add(a, b, 1 if op is Op.ADD else -1)
+        if op is Op.DIV and self.wide:
+            a = self.lin(i.operands[0], depth + 1)
+            b = self.lin(i.operands[1], depth + 1)
+            if a is None or b is None or b.c \
+                    or lane_stride(a) not in (0, 1):
+                return None
+            return _Lin(layout=a.layout, has_scalar=True)
         if op is Op.MUL:
-            a = classify(i.operands[0], depth + 1)
-            b = classify(i.operands[1], depth + 1)
+            a = self.lin(i.operands[0], depth + 1)
+            b = self.lin(i.operands[1], depth + 1)
             if a is None or b is None:
                 return None
             for x, y, yv in ((a, b, i.operands[1]), (b, a, i.operands[0])):
@@ -332,12 +401,12 @@ def affine_mem_facts(fn: Function) -> _MemFacts:
             for x, yv in ((a, i.operands[1]), (b, i.operands[0])):
                 nz = {s for s, cv in x.c.items() if cv}
                 if nz == {"gy"} and _is_uniform_product(
-                        yv, defs, slot_stores, entry_ids,
+                        yv, defs, slot_stores, self.entry_ids,
                         ("global_size", ("num_groups", "local_size"))):
                     return _Lin({"gys": x.c["gy"]}, True,
                                 x.has_scalar or x.const_abs != 0)
                 if nz == {"grpy"} and _is_uniform_product(
-                        yv, defs, slot_stores, entry_ids,
+                        yv, defs, slot_stores, self.entry_ids,
                         ("num_groups", None)):
                     return _Lin({"grpys": x.c["grpy"]}, x.layout,
                                 x.has_scalar or x.const_abs != 0)
@@ -346,10 +415,26 @@ def affine_mem_facts(fn: Function) -> _MemFacts:
             return None
         return None
 
+
+def lane_stride(lin: _Lin) -> int:
+    """The form's stride along the lanes of a row (its gx/lx/lane
+    coefficients; every other term is constant along a row under the
+    launch-layout condition)."""
+    return sum(lin.c.get(s, 0) for s in _LANE_SYMS)
+
+
+def affine_mem_facts(fn: Function) -> _MemFacts:
+    """Classify every LOAD/STORE/ATOMIC index of ``fn`` (memoized on the
+    function, keyed by its ir_version)."""
+    cached = getattr(fn, "_mem_facts", None)
+    if cached is not None and cached[0] == fn.ir_version:
+        return cached[1]
+    classify = _Classifier(fn, wide=False).lin
+
     def index_fact(lin: Optional[_Lin]) -> Optional[AffineFact]:
         if lin is None:
             return None
-        stride = sum(lin.c.get(s, 0) for s in _LANE_SYMS)
+        stride = lane_stride(lin)
         if stride == 0:
             return AffineFact("uni", lin.layout)
         if lin.has_scalar:
@@ -375,39 +460,69 @@ def affine_mem_facts(fn: Function) -> _MemFacts:
     for i in fn.instructions():
         op = i.op
         if op is Op.LOAD:
-            f = index_fact(classify(i.operands[1], 0))
+            f = index_fact(classify(i.operands[1]))
             if f is not None:
                 facts.index_fact[id(i)] = f
         elif op is Op.STORE:
-            lin = classify(i.operands[1], 0)
+            lin = classify(i.operands[1])
             f = index_fact(lin)
             if f is not None:
                 facts.index_fact[id(i)] = f
             facts.store_privacy[id(i)] = privacy(lin)
         elif op is Op.ATOMIC:
-            f = index_fact(classify(i.operands[2], 0))
+            f = index_fact(classify(i.operands[2]))
             if f is not None:
                 facts.index_fact[id(i)] = f
     fn._mem_facts = (fn.ir_version, facts)  # type: ignore[attr-defined]
     return facts
 
 
+#: window candidate class by lane stride (``window_classes``)
+_WINDOW_CLASS = {1: "window", 0: "row_uniform"}
+
+
+def window_classes(fn: Function) -> Dict[int, str]:
+    """id(LOAD/STORE) -> its window candidate class, for the accesses
+    whose index classifies (``_Classifier(wide=True)``) with lane stride
+    1 ("window": ``base[row] + lane``) or 0 ("row_uniform": one index a
+    row).  A candidate is a claim about the index's form, not a licence:
+    the rule for ``//`` and the dominating-store rule hold only on some
+    launches, so the lowering checks every candidate on the device
+    before it serves the access as a window.  Data-dependent indices get
+    no class."""
+    classify = _Classifier(fn, wide=True).lin
+    out: Dict[int, str] = {}
+    for i in fn.instructions():
+        if i.op in (Op.LOAD, Op.STORE):
+            lin = classify(i.operands[1])
+            cls = None if lin is None else _WINDOW_CLASS.get(
+                lane_stride(lin))
+            if cls is not None:
+                out[id(i)] = cls
+    return out
+
+
 def export_codegen_facts(fn: Function) -> Dict[str, Dict]:
-    """Positional view of ``affine_mem_facts`` for code generators.
+    """Positional view of ``affine_mem_facts`` and ``window_classes``
+    for code generators.
 
     Backends that re-emit the function (rather than walking the live
     ``Instr`` objects) cannot key on ``id(instr)``; they address
     instructions as ``(block_index, instr_index)``.  Returns
 
       ``{"index":         {(bi, ii): (kind, layout, span_mul, span_add)},
-         "store_private": {(bi, ii): "2d" | "1d" | None}}``
+         "store_private": {(bi, ii): "2d" | "1d" | None},
+         "window":        {(bi, ii): "window" | "row_uniform"}}``
 
     covering exactly the accesses ``affine_mem_facts`` proved (loads /
-    stores / atomics for "index"; every STORE for "store_private").
+    stores / atomics for "index"; every STORE for "store_private") and
+    the loads and stores ``window_classes`` gave a class.
     """
     facts = affine_mem_facts(fn)
+    windows = window_classes(fn)
     index: Dict[Tuple[int, int], Tuple[str, bool, int, int]] = {}
     store_private: Dict[Tuple[int, int], Optional[str]] = {}
+    window: Dict[Tuple[int, int], str] = {}
     for bi, b in enumerate(fn.blocks):
         for ii, i in enumerate(b.instrs):
             f = facts.index_fact.get(id(i))
@@ -416,7 +531,10 @@ def export_codegen_facts(fn: Function) -> Dict[str, Dict]:
                                    f.span_add)
             if i.op is Op.STORE:
                 store_private[(bi, ii)] = facts.store_privacy.get(id(i))
-    return {"index": index, "store_private": store_private}
+            if id(i) in windows:
+                window[(bi, ii)] = windows[id(i)]
+    return {"index": index, "store_private": store_private,
+            "window": window}
 
 
 _NULL = AnalysisManager(enabled=False)
@@ -430,4 +548,4 @@ def ensure_manager(am: Optional[AnalysisManager]) -> AnalysisManager:
 
 
 __all__ = ["AnalysisManager", "affine_mem_facts", "ensure_manager",
-           "export_codegen_facts"]
+           "export_codegen_facts", "launch_uniform", "window_classes"]

@@ -31,7 +31,8 @@ sys.path.insert(0, str(Path(__file__).parent / "kernels"))
 
 from repro.core import interp, interp_mem
 from repro.core.interp_mem import AffineFact
-from repro.core.passes.analysis import affine_mem_facts
+from repro.core.frontends import opencl
+from repro.core.passes.analysis import affine_mem_facts, window_classes
 from repro.core.passes.pipeline import ABLATION_LADDER, run_pipeline
 from repro.core.vir import Op
 from repro.volt_bench import BENCHES
@@ -180,6 +181,48 @@ def test_affine_facts_on_compiled_benches():
     # row_ptr[gid]/row_ptr[gid+1] classify; vals[e]/x[cols[e]] must not
     classified = sum(id(i) in facts.index_fact for i in loads)
     assert 0 < classified < len(loads)
+
+
+@opencl.kernel
+def div_by_lane(x: "ptr_f32 const", y: "ptr_f32", n: "i32 uniform"):
+    gid = get_global_id(0)  # noqa: F821 - an intrinsic of the dialect
+    if gid < n:
+        y[gid] = x[gid // (get_local_id(0) + 1)]  # noqa: F821
+
+
+def _window_class_by_buffer(fn):
+    """{buffer param name: window class or None} over the loads and
+    stores of ``fn`` (one access per buffer in these kernels)."""
+    cls = window_classes(fn)
+    return {i.operands[0].name: cls.get(id(i))
+            for i in fn.instructions() if i.op in (Op.LOAD, Op.STORE)}
+
+
+@pytest.mark.parametrize("name, want", [
+    # gid: lane stride 1 everywhere
+    ("vecadd", {"x": "window", "y": "window", "z": "window"}),
+    # a[row*k + i]: row = gid // n is a per-row term and the counter i is
+    # only ever assigned launch-uniform values; b[i*n + col]: col is
+    # gid less that term
+    ("sgemm", {"a": "row_uniform", "b": "window", "c": "window"}),
+    # vals[e], cols[e], x[cols[e]]: e is loaded from row_ptr
+    ("spmv_csr", {"row_ptr": "window", "vals": None, "cols": None,
+                  "x": None, "y": "window"}),
+])
+def test_window_classes_on_compiled_benches(name, want):
+    fn = _compiled(BENCHES[name].handle, name)
+    assert _window_class_by_buffer(fn) == want
+
+
+def test_window_class_needs_a_uniform_divisor():
+    fn = _compiled(div_by_lane, "div_by_lane")
+    assert _window_class_by_buffer(fn) == {"x": None, "y": "window"}
+    # the wide rules are for candidates only: the interpreter's facts
+    # keep sgemm's loads unclassified
+    fn = _compiled(BENCHES["sgemm"].handle, "sgemm")
+    facts = affine_mem_facts(fn)
+    assert not any(id(i) in facts.index_fact for i in fn.instructions()
+                   if i.op is Op.LOAD)
 
 
 def test_2d_linear_id_store_privacy():

@@ -15,6 +15,7 @@ file.
 import importlib.util
 import json
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -103,13 +104,48 @@ def _spmv_kron():
     return compile_kernel(kernel).fn, bufs, scalars, params
 
 
+@pytest.fixture(scope="module")
+def compiled(lowered):
+    """(name, tier) -> the chunk program compiled for the described
+    chip."""
+    done = {}
+
+    def get(name, tier):
+        if (name, tier) not in done:
+            done[name, tier] = lowered(name).compile(
+                compiler_options=jaxgen._TIER_OPTIONS[tier])
+        return done[name, tier]
+
+    return get
+
+
 @pytest.mark.parametrize("tier", TIERS)
 @pytest.mark.parametrize("name", KERNELS)
-def test_chunk_program_compiles_for_v5e(lowered, name, tier):
-    exe = lowered(name).compile(
-        compiler_options=jaxgen._TIER_OPTIONS[tier])
+def test_chunk_program_compiles_for_v5e(compiled, name, tier):
+    exe = compiled(name, tier)
     mem = exe.memory_analysis()
     # one chunk's arguments, outputs and scratch fit the chip's 16 GB
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < 16 * 2**30
+
+
+#: a gather in the compiled text: its result's element type and
+#: dimensions, and the op name of its metadata
+_GATHER = re.compile(r"= \w+\[([\d,]*)\]\S* gather\(.*?op_name=\"([^\"]*)\"")
+
+
+@pytest.mark.parametrize("name", ["vecadd", "sgemm"])
+def test_affine_accesses_compile_to_windows(compiled, name):
+    """Every load of these kernels is a window candidate, so the only
+    gathers of one element per lane of a chunk (R·W: 256 rows of 32,
+    one workgroup of one warp a row) are the fallbacks, under
+    ``jaxgen._GATHER_SCOPE``.  A window or broadcast that the compiler
+    turned back into an element gather fails here."""
+    rw = jaxgen._CHUNK_WGS * 32
+    gathers = [(int(np.prod([int(d) for d in dims.split(",") if d])), op)
+               for dims, op in _GATHER.findall(
+                   compiled(name, "fast").as_text())]
+    element = [op for n, op in gathers if n == rw]
+    assert element and all(jaxgen._GATHER_SCOPE in op for op in element), \
+        element
