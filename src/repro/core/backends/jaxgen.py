@@ -47,12 +47,15 @@ Certification gate (the promotion state machine, docs/performance.md
 "Execute side 5"): a (kernel ir_version, launch shape class) pair starts
 UNKNOWN.  The first licensed launch runs BOTH the jitted program and the
 normal executor chain, compares buffers byte-for-byte and stats
-field-for-field, and records "pass"/"fail" — in memory and, when the
-runtime installed ``interp.JAX_CERT_HOOKS``, in a ``.vjc`` file next to
-the ``.vck``/``.vdp`` caches.  Only a recorded "pass" lets later
-launches run JAX as the primary; any recorded "fail" pins the pair to
-the normal chain forever (until the kernel IR changes).  Evidence
-promotes the fast path, not static analysis alone.
+field-for-field, and records "pass"/"fail" — in memory and in a
+``.vjc`` file next to the ``.vck``/``.vdp`` caches (``core/diskcache``).
+Only a recorded "pass" lets later launches run JAX as the primary; any
+recorded "fail" pins the pair to the normal chain forever (until the
+kernel IR changes).  Evidence promotes the fast path, not static
+analysis alone.
+
+Entry: ``serve`` runs one launch on this rung, or declines it and says
+why, for ``runtime.interp_launch`` to run the grid rung instead.
 
 Failure model: the trace never raises mid-chunk.  Semantic errors the
 oracle would raise (OOB store, uniformity violation, fuel exhaustion)
@@ -77,6 +80,7 @@ import jax.numpy as jnp
 from ..vir import (AddrSpace, BINOPS, Const, Function, GlobalVar, Instr,
                    Op, Param, Reg, Slot, Ty, UNOPS, Value)
 from .. import graph
+from .. import diskcache as _diskcache
 from .. import faults as _faults
 from .. import governor as _gov
 from .. import interp as _interp
@@ -1267,9 +1271,15 @@ class _Compiled:
         return exe
 
 
-def _licence(fn: Function, params, n_wg: int, argmap: dict,
+def _licence(fn: Function, params, buffers: dict, scalar_args: dict,
              globals_mem) -> None:
     """Static gates — raises LowerError on any licence miss."""
+    try:
+        argmap = _interp.kernel_args(fn, buffers, scalar_args,
+                                     params.warp_size)
+    except _interp.ExecError as e:
+        raise LowerError(str(e)) from e
+    n_wg = params.grid * params.grid_y
     if params.warp_size > 32:
         raise LowerError("warp size > 32")
     if params.strict_oob_loads:
@@ -1370,12 +1380,8 @@ def _trace(fn: Function, params, buffers: dict, scalar_args: dict,
 
     buf_names = tuple(sorted(buffers))
     scalar_names = tuple(sorted(scalar_args))
-    scalar_dtypes = {}
-    for p in fn.params:
-        if p.ty is not Ty.PTR:
-            if p.name not in scalar_args:
-                raise LowerError(f"no scalar bound for {p.name}")
-            scalar_dtypes[p.name] = _TY_NP[p.ty]
+    scalar_dtypes = {p.name: _TY_NP[p.ty] for p in fn.params
+                     if p.ty is not Ty.PTR}
     tiles = {f"@{g.name}": (g.size, _TY_DTYPE[g.elem_ty])
              for g in fn.shared}
     ops: set = set()
@@ -1489,7 +1495,7 @@ def _chunk_width(params) -> int:
 
 
 def _prepare(fn: Function, params, buffers: dict, scalar_args: dict,
-             argmap: dict, globals_mem) -> _Compiled:
+             globals_mem) -> _Compiled:
     """The traced, lowered program of this (kernel, launch shape), with
     its fast tier compiled.  A licence miss or a trace failure is a
     ``LowerError`` and is cached for the kernel's IR version; a backend
@@ -1509,8 +1515,7 @@ def _prepare(fn: Function, params, buffers: dict, scalar_args: dict,
         JAX_TELEMETRY["trace_cache_hits"] += 1
     else:
         try:
-            _licence(fn, params, params.grid * params.grid_y, argmap,
-                     globals_mem)
+            _licence(fn, params, buffers, scalar_args, globals_mem)
             rec = _trace(fn, params, buffers, scalar_args, cw)
             # every LowerError surfaces here, at trace time, before
             # anything runs or any verdict is recorded
@@ -1664,14 +1669,8 @@ def _certs(fn: Function) -> dict:
     c = getattr(fn, "_jax_certs", None)
     if c is not None and c[0] == fn.ir_version:
         return c[1]
-    d = None
-    hooks = _interp.JAX_CERT_HOOKS
-    if hooks is not None:
-        try:
-            d = hooks[0](fn)
-        except Exception:
-            d = None
-    if not isinstance(d, dict):
+    d = _diskcache.load_verdicts(fn)
+    if d is None:
         d = {}
     fn._jax_certs = (fn.ir_version, d)  # type: ignore[attr-defined]
     return d
@@ -1707,12 +1706,7 @@ def _record(fn: Function, sig: str, verdict: str,
     if detail is None:
         detail = _detail_of(certs.get(sig))
     certs[sig] = (verdict, jax_ms, grid_ms, detail)
-    hooks = _interp.JAX_CERT_HOOKS
-    if hooks is not None:
-        try:
-            hooks[1](fn, certs)
-        except Exception:
-            pass
+    _diskcache.save_verdicts(fn, certs)
 
 
 # --------------------------------------------------------------------------
@@ -1751,22 +1745,8 @@ def licence_check(fn: Function, params, buffers: dict,
     licence AND trace cleanly?  Used by the conformance suite's
     engagement assertions; performs no execution and records no
     verdicts."""
-    scalar_args = scalar_args or {}
-    argmap: dict = {}
-    for p in fn.params:
-        if p.ty is Ty.PTR:
-            if p.name not in buffers:
-                return (False, f"no buffer bound for {p.name}")
-            argmap[id(p)] = buffers[p.name]
-        else:
-            if p.name not in scalar_args:
-                return (False, f"no scalar bound for {p.name}")
-            argmap[id(p)] = np.full(params.warp_size,
-                                    scalar_args[p.name],
-                                    dtype=_TY_NP[p.ty])
     try:
-        _prepare(fn, params, buffers, scalar_args, argmap,
-                 globals_mem or {})
+        _prepare(fn, params, buffers, scalar_args or {}, globals_mem or {})
     except _faults.InjectedFault:
         raise
     except (LowerError, _faults.EngineFault) as e:
@@ -1775,7 +1755,7 @@ def licence_check(fn: Function, params, buffers: dict,
 
 
 def _certify(rec: _Compiled, fn: Function, buffers: dict,
-             scalar_args: dict, params, stats, mode, run_normal) -> bool:
+             scalar_args: dict, params, stats, run_normal) -> None:
     """The differential certification run of one (kernel, launch shape):
     the launch's results come from the normal chain (``run_normal``),
     and the jitted program's run beside it decides the verdict —
@@ -1783,8 +1763,7 @@ def _certify(rec: _Compiled, fn: Function, buffers: dict,
     is), "fail" (the exact tier differs too; the detail names the first
     difference) or "error" (the jitted program raised; the detail holds
     the text).  Raises what the normal chain raises, and an infra fault
-    of the jitted run before the normal chain has run (in "fallback"
-    mode it returns False instead: nothing ran)."""
+    of the jitted run before the normal chain has run (nothing ran)."""
     JAX_TELEMETRY["cert_runs"] += 1
     t_cert = perf_counter()
     # run_normal mutates buffers in place below; the exact tier (tried
@@ -1810,8 +1789,6 @@ def _certify(rec: _Compiled, fn: Function, buffers: dict,
         # an INFRA fault interrupted the certification — record no
         # verdict (the pair stays unknown and re-certifies later)
         JAX_TELEMETRY["demotions"] += 1
-        if mode == "fallback":
-            return False
         raise
     try:
         t0 = perf_counter()
@@ -1831,7 +1808,7 @@ def _certify(rec: _Compiled, fn: Function, buffers: dict,
         _record(fn, rec.sig, "pass", grid_ms=grid_ms, detail="")
         JAX_TELEMETRY["certified"] += 1
         rec.cert_s = perf_counter() - t_cert
-        return True
+        return
     if miss:
         notes.append(f"fast tier: {miss}")
     # ---- exact-tier retry -------------------------------------------
@@ -1845,7 +1822,7 @@ def _certify(rec: _Compiled, fn: Function, buffers: dict,
         # the normal chain; leave the pair unknown so a later launch
         # re-certifies
         JAX_TELEMETRY["demotions"] += 1
-        return True
+        return
     miss = exact and _disagreement(*exact, buffers, stats)
     if exact and miss is None:
         verdict = "pass-exact"
@@ -1859,42 +1836,55 @@ def _certify(rec: _Compiled, fn: Function, buffers: dict,
             grid_ms=grid_ms if verdict == "pass-exact" else None,
             detail="; ".join(notes))
     rec.cert_s = perf_counter() - t_cert
-    return True
 
 
-def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
-                mem, argmap: dict, stats, mode, run_normal,
-                route: bool = False) -> bool:
-    """The jax rung's launch entry, called from ``interp._launch_impl``
-    with the "jax" rung pushed.  Returns True when THIS call produced
-    the launch's results (either the jitted program ran as the
-    certified primary, or a certification run drove ``run_normal``);
-    False means nothing happened and the caller falls through to the
-    normal executor selection.
+def serve(fn: Function, buffers: dict, params, scalar_args: dict,
+          globals_mem, stats, run_normal) -> str:
+    """The jax rung's entry for one launch, called by
+    ``runtime.interp_launch`` with the "jax" rung pushed.  Returns
+    "served" when this call produced the launch's results in ``buffers``
+    and ``stats``: the jitted program ran as the certified primary, or a
+    differential certification run drove ``run_normal(stats)``, the grid
+    rung.  Otherwise it declines, with ``buffers`` and ``stats``
+    untouched, and returns why:
 
-    ``mode``: True (chain rung — failures raise EngineFault so the
-    runtime demotes + rolls back) or "fallback" (standalone — failures
-    silently fall through, buffers untouched either way).
+      * "refused": outside the licence, or the trace failed;
+      * "routed": the small-launch router — the pair is certified, but
+        its measured grid time beats the jitted dispatch floor
+        (``_ROUTE_MARGIN``), so the grid rung serves it;
+      * "fail" / "error": the pair's recorded verdict.
 
-    ``route``: enable the small-launch dispatch router (the Runtime
-    chain's ``jax="route"`` mode) — pairs whose measured grid time
-    beats the jitted dispatch floor are declined so they land on the
-    grid rung.  Direct ``jax=True`` calls (conformance sweeps, the
-    jax-vs-grid benchmarks) keep unconditional engagement.
-    """
+    A failure raises: a ``KernelFault`` as the normal chain raises it,
+    or an ``EngineFault`` with the buffers untouched, for the caller to
+    demote.  Any other exception inside the rung becomes an
+    ``EngineFault`` here.  Like the host executors, the rung records
+    itself in ``interp.LAST_EXECUTOR[0]``."""
+    _interp.LAST_EXECUTOR[0] = "jax"
+    try:
+        return _serve(fn, buffers, params, scalar_args, globals_mem, stats,
+                      run_normal)
+    except (_faults.KernelFault, _faults.EngineFault):
+        raise
+    except Exception as e:
+        JAX_TELEMETRY["demotions"] += 1
+        raise _faults.EngineFault(
+            f"jax executor failure: {type(e).__name__}: {e}",
+            site="jax.exec", rung="jax") from e
+
+
+def _serve(fn: Function, buffers: dict, params, scalar_args: dict,
+           globals_mem, stats, run_normal) -> str:
+    if params.grid * params.grid_y <= 1 or params.strict_oob_loads:
+        return "refused"       # outside the licence without a scan
     try:
         with span("volt.jax.prepare"):
-            rec = _prepare(fn, params, buffers, scalar_args, argmap,
-                           mem.globals_mem)
+            rec = _prepare(fn, params, buffers, scalar_args,
+                           globals_mem or {})
     except LowerError:
         JAX_TELEMETRY["refusals"] += 1
-        return False
-    except _faults.KernelFault:
-        raise
+        return "refused"
     except _faults.EngineFault:
         JAX_TELEMETRY["demotions"] += 1
-        if mode == "fallback":
-            return False
         raise
 
     if _faults.ACTIVE:
@@ -1902,33 +1892,29 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
             _faults.maybe_fault("jax.cache.load")
         except _faults.InjectedFault:
             JAX_TELEMETRY["demotions"] += 1
-            if mode == "fallback":
-                return False
             raise
     verdict, v_jax_ms, v_grid_ms = _verdict_of(_certs(fn).get(rec.sig))
 
     if verdict in ("fail", "error"):
-        return False
+        return verdict
 
     # ---- small-launch dispatch router --------------------------------
     # A certified pair whose measured grid time beats the measured
     # jitted time (dominated by the per-dispatch jit-call floor for
-    # small launches) is SERVED BY THE GRID RUNG: falling through here
-    # lands exactly there, with the verdict untouched — a bigger shape
-    # class of the same kernel still takes the jitted primary.
-    if (route and verdict is not None and v_jax_ms is not None
+    # small launches) is SERVED BY THE GRID RUNG, with the verdict
+    # untouched — a bigger shape class of the same kernel still takes
+    # the jitted primary.
+    if (verdict is not None and v_jax_ms is not None
             and v_grid_ms is not None
             and v_grid_ms < v_jax_ms * _ROUTE_MARGIN):
         JAX_TELEMETRY["routed_small"] += 1
-        hook = getattr(_interp, "ROUTED_SMALL_HOOK", None)
-        if hook is not None:
-            hook()
-        return False
+        return "routed"
 
     if verdict is None:
         with span("volt.jax.certify"):
-            return _certify(rec, fn, buffers, scalar_args, params, stats,
-                            mode, run_normal)
+            _certify(rec, fn, buffers, scalar_args, params, stats,
+                     run_normal)
+        return "served"
 
     # ---- certified primary ------------------------------------------
     tier = "exact" if verdict == "pass-exact" else "fast"
@@ -1936,20 +1922,9 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
     try:
         host_bufs, jstats = _run(rec, fn, buffers, scalar_args, params,
                                  tier=tier)
-    except _faults.KernelFault:
-        raise
     except _faults.EngineFault:
         JAX_TELEMETRY["demotions"] += 1
-        if mode == "fallback":
-            return False
         raise
-    except Exception as e:
-        JAX_TELEMETRY["demotions"] += 1
-        if mode == "fallback":
-            return False
-        raise _faults.EngineFault(
-            f"jax executor failure: {type(e).__name__}: {e}",
-            site="jax.exec", rung="jax") from e
     with span("volt.jax.apply"):
         _apply(host_bufs, jstats, buffers, stats)
     JAX_TELEMETRY["engaged"] += 1
@@ -1966,4 +1941,4 @@ def orchestrate(fn: Function, buffers: dict, params, scalar_args: dict,
         _record(fn, rec.sig, verdict,
                 jax_ms=(perf_counter() - t0) * 1e3,
                 grid_ms=v_grid_ms)
-    return True
+    return "served"

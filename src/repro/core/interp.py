@@ -32,6 +32,7 @@ from typing import Any, Dict, Generator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import diskcache as _diskcache
 from . import faults as _faults
 from . import governor as _gov
 from . import interp_mem as _mem
@@ -114,7 +115,7 @@ class LaunchParams:
 
 def fold_warps(params: LaunchParams, factor: int = 4) -> LaunchParams:
     """Refold a 1D launch into ``factor``-warp workgroups (shared by the
-    benchmarks and the executor-conformance tests so every consumer
+    executor-conformance tests and their neighbours so every consumer
     folds identically).  The folded launch covers AT LEAST the original
     thread range; when the workgroup count is not divisible by
     ``factor`` the last workgroup rounds up, so kernels must guard their
@@ -1181,7 +1182,7 @@ class _DProgram:
                         self.slot_meta.append(o)
         self.n_regs = len(self.reg_idx)
         self.n_slots = len(self.slot_idx)
-        # fusion telemetry (benchmarks / tests): dynamic-table shrinkage
+        # fusion telemetry (tests): dynamic-table shrinkage
         self.n_run_instrs = 0
         self.n_run_handlers = 0
         self._bidx = {id(b): k for k, b in enumerate(fn.blocks)}
@@ -1797,18 +1798,15 @@ _BARRIER = object()   # per-warp node (batched program): top-level barrier
 
 def _decode_batched(fn: Function, W: int, strict: bool, n_warps: int,
                     grid_mode: bool = False,
-                    ride_along: bool = True,
                     wg_rows: int = 1,
                     coalesced: bool = False) -> "_BProgram":
     """Decode ``fn`` for workgroup-batched execution (memoized like
     _decode, in the same ir_version-keyed cache).  ``grid_mode`` batches
     independent workgroups (rows are warps grouped ``wg_rows`` per
     workgroup; a barrier synchronizes only the rows of its own
-    workgroup); ``ride_along=False`` restores the stricter
-    desync-on-mixed-loop-exit behavior (used as a benchmark baseline).
-    ``coalesced`` decodes for the multi-launch coalescing path: global
-    LOAD/STORE handlers index per-tenant staging tables and statistics
-    route through the per-tenant stripe."""
+    workgroup).  ``coalesced`` decodes for the multi-launch coalescing
+    path: global LOAD/STORE handlers index per-tenant staging tables and
+    statistics route through the per-tenant stripe."""
     if _faults.ACTIVE:
         _faults.maybe_fault("decode")
     cache = getattr(fn, "_decode_cache", None)
@@ -1816,14 +1814,13 @@ def _decode_batched(fn: Function, W: int, strict: bool, n_warps: int,
         cache = {}
         fn._decode_cache = cache  # type: ignore[attr-defined]
     key = (fn.ir_version, W, bool(strict), "wg", n_warps, bool(grid_mode),
-           bool(ride_along), int(wg_rows), bool(coalesced))
+           int(wg_rows), bool(coalesced))
     prog = cache.get(key)
     if prog is None:
         for k in [k for k in cache if k[0] != fn.ir_version]:
             del cache[k]
         prog = _BProgram(fn, W, bool(strict), n_warps, grid_mode=grid_mode,
-                         ride_along=ride_along, wg_rows=wg_rows,
-                         coalesced=coalesced)
+                         wg_rows=wg_rows, coalesced=coalesced)
         cache[key] = prog
     return prog
 
@@ -1951,31 +1948,15 @@ def _ordering_sensitive(fn: Function, _seen: Optional[set] = None) -> bool:
 # Decode plans — the decoder's per-function STATIC analysis (affine index
 # facts, store privacy, cyclic blocks, ordering sensitivity, callee
 # purity) bundled into one serializable record.  Computed once per
-# (function, ir_version) and memoized on the function; when core.runtime
-# installs DECODE_PLAN_HOOKS, plans also persist on disk next to the
-# compile cache, keyed by a content hash of the function (plus transitive
-# callees), so a second process decoding an identical kernel skips the
-# whole static scan.  The decoded HANDLER TABLES are closures and never
-# persist — only the analysis does.  Stale entries are impossible (any
-# IR change changes the content hash); corrupt entries fall back to a
-# fresh computation.
+# (function, ir_version) and memoized on the function; plans also
+# persist on disk next to the compile cache (``.vdp``, core/diskcache),
+# keyed by a content hash of the function (plus transitive callees), so
+# a second process decoding an identical kernel skips the whole static
+# scan.  The decoded HANDLER TABLES are closures and never persist —
+# only the analysis does.  Stale entries are impossible (any IR change
+# changes the content hash); corrupt entries fall back to a fresh
+# computation.
 # --------------------------------------------------------------------------
-
-#: (loader(fn) -> plan | None, saver(fn, plan)) installed by core.runtime
-DECODE_PLAN_HOOKS: Optional[Tuple[Any, Any]] = None
-
-#: (loader(fn) -> {shape-sig: "pass"|"fail"} | None, saver(fn, certs))
-#: installed by core.runtime for the jax rung's differential
-#: certification verdicts (.vjc files, next to .vck/.vdp)
-JAX_CERT_HOOKS: Optional[Tuple[Any, Any]] = None
-
-#: zero-arg callable installed by core.runtime: the jax rung's dispatch
-#: router calls it when a certified launch is sent to the grid rung
-#: because the measured grid time beats the jitted-dispatch floor
-#: (LAUNCH_TELEMETRY["routed_small"])
-ROUTED_SMALL_HOOK: Optional[Any] = None
-
-_DECODE_PLAN_SCHEMA = 1
 
 
 def _compute_decode_plan(fn: Function) -> Tuple[Dict[str, Any], Any]:
@@ -2002,7 +1983,7 @@ def _compute_decode_plan(fn: Function) -> Tuple[Dict[str, Any], Any]:
                                              f.span_mul, f.span_add),
                      priv))
     plan = {
-        "schema": _DECODE_PLAN_SCHEMA,
+        "schema": _diskcache.PLAN_SCHEMA,
         "facts": fact_rows,
         "privacy": _store_privacy(fn),
         "cyclic": cyclic_bis,
@@ -2037,29 +2018,20 @@ def _materialize_facts(fn: Function, plan: Dict[str, Any]):
 
 def _decode_plan(fn: Function) -> Dict[str, Any]:
     """The function's decode plan (memoized by ir_version; disk-backed
-    when DECODE_PLAN_HOOKS is installed)."""
+    in ``.vdp`` files)."""
     cached = getattr(fn, "_decode_plan", None)
     if cached is not None and cached[0] == fn.ir_version:
         return cached[1]
-    plan = None
+    plan = _diskcache.load_plan(fn)
     facts = None
-    if DECODE_PLAN_HOOKS is not None:
+    if plan is not None:
         try:
-            plan = DECODE_PLAN_HOOKS[0](fn)
-            if plan is not None:
-                if plan.get("schema") != _DECODE_PLAN_SCHEMA:
-                    raise ValueError("decode plan schema mismatch")
-                facts = _materialize_facts(fn, plan)
+            facts = _materialize_facts(fn, plan)
         except Exception:
-            plan = None            # corrupt/stale payload: recompute
-            facts = None
+            plan = None            # out of sync with the IR: recompute
     if plan is None:
         plan, facts = _compute_decode_plan(fn)
-        if DECODE_PLAN_HOOKS is not None:
-            try:
-                DECODE_PLAN_HOOKS[1](fn, plan)
-            except Exception:
-                pass
+        _diskcache.save_plan(fn, plan)
     plan = dict(plan)
     plan["facts_obj"] = facts
     fn._decode_plan = (fn.ir_version, plan)  # type: ignore[attr-defined]
@@ -2077,11 +2049,9 @@ class _BProgram(_DProgram):
 
     def __init__(self, fn: Function, W: int, strict: bool,
                  n_warps: int, *, grid_mode: bool = False,
-                 ride_along: bool = True, wg_rows: int = 1,
-                 coalesced: bool = False) -> None:
+                 wg_rows: int = 1, coalesced: bool = False) -> None:
         self.n_warps = n_warps
         self.grid_mode = grid_mode
-        self.ride_along = ride_along
         # multi-launch coalescing decode: rows belong to different
         # launches (tenants); global LOAD/STOREs index (k, size) staging
         # tables by the stripe's per-row tenant column and statistics
@@ -2109,15 +2079,8 @@ class _BProgram(_DProgram):
         # its workgroup's barrier group, so the wg-mode rule applies.
         self.has_barrier = any(i.op is Op.BARRIER
                                for i in fn.instructions())
-        barrier_safe = ((grid_mode and wg_rows == 1)
+        self.ride_ok = ((grid_mode and wg_rows == 1)
                         or not self.has_barrier)
-        # mixed vx_split ride-along: PR 2 behavior, always on where safe.
-        self.split_ride_ok = barrier_safe
-        # vx_pred loop ride-along: the PR 3 extension; ride_along=False
-        # restores the PR 2 desync-on-mixed-loop-exit baseline WITHOUT
-        # touching the split ride-along (benchmark comparisons would be
-        # inflated otherwise).
-        self.pred_ride_ok = ride_along and barrier_safe
         # Grid mode interleaves INDEPENDENT workgroups per instruction.
         # Within one DYNAMIC execution of a store the row-major scatter
         # reproduces the oracle's last-workgroup-wins order on a cell
@@ -2697,7 +2660,7 @@ class _BProgram(_DProgram):
             else_i = self._bidx[id(i.operands[2])]
             label = b.label
 
-            ride_ok = self.split_ride_ok
+            ride_ok = self.ride_ok
 
             def bcbr_node(st, gc_=gc_, then_i=then_i, else_i=else_i,
                           opv=opv, label=label, fname=fname, nw=nw,
@@ -2788,7 +2751,7 @@ class _BProgram(_DProgram):
             outside_i = self._bidx[id(i.operands[3])]
             attrs = i.attrs
 
-            ride_ok = self.pred_ride_ok
+            ride_ok = self.ride_ok
 
             def bpred_node(st, gc_=gc_, tok_i=tok_i, inside_i=inside_i,
                            outside_i=outside_i, attrs=attrs, opv=opv,
@@ -2898,15 +2861,13 @@ class _BProgram(_DProgram):
             binders = tuple(binders)
             strict = self.strict
             grid_mode = self.grid_mode
-            ride_along = self.ride_along
             wg_rows = self.wg_rows if grid_mode else 1
             coalesced = self.coalesced
 
             def bcall_node(st, callee=callee, binders=binders, ri=ri,
                            ret_dtype=ret_dtype, opv=opv, W=W, nw=nw,
                            strict=strict, grid_mode=grid_mode,
-                           ride_along=ride_along, wg_rows=wg_rows,
-                           coalesced=coalesced):
+                           wg_rows=wg_rows, coalesced=coalesced):
                 mask = st.mask
                 act = st.act_rows
                 n_act = st.active
@@ -2938,7 +2899,6 @@ class _BProgram(_DProgram):
                         raise ExecError("pointer arg must be param/global")
                 cprog = _decode_batched(callee, W, strict, nw,
                                         grid_mode=grid_mode,
-                                        ride_along=ride_along,
                                         wg_rows=wg_rows,
                                         coalesced=coalesced)
                 sub = _DState(cprog, cargs, mask.copy(), st.ctx, st.mem,
@@ -3808,7 +3768,6 @@ def _split_batch(bprog: "_BProgram", bst: _DState,
         sub_wgs *= 2
     subprog = _decode_batched(bprog.fn, W, bprog.strict,
                               sub_wgs * wg_rows, grid_mode=True,
-                              ride_along=bprog.ride_along,
                               wg_rows=wg_rows)
     idx = [r for g in gs
            for r in range(g * wg_rows, (g + 1) * wg_rows)]
@@ -4323,7 +4282,6 @@ def launch_coalesced(module_fn: Function,
                 if nc not in plans:
                     gp = _decode_batched(fn, W, False, nc * n_warps,
                                          grid_mode=True,
-                                         ride_along=True,
                                          wg_rows=n_warps,
                                          coalesced=True)
                     if not (gp.order_free and gp.private_stores):
@@ -4397,7 +4355,6 @@ def launch_coalesced(module_fn: Function,
                 for (c0, nc) in spans:
                     gprog = _decode_batched(fn, W, False, nc * n_warps,
                                             grid_mode=True,
-                                            ride_along=True,
                                             wg_rows=n_warps,
                                             coalesced=True)
                     if not gprog.order_free:
@@ -4473,14 +4430,31 @@ def _kernel_globals(fn: Function) -> List[GlobalVar]:
 # front-end inserts; here it lives in the host runtime)
 # --------------------------------------------------------------------------
 
+def kernel_args(fn: Function, buffers: Dict[str, np.ndarray],
+                scalar_args: Dict[str, Any], W: int) -> Dict[int, Any]:
+    """The kernel's argument map, ``id(param) -> array``: each pointer
+    parameter's bound buffer, each scalar broadcast over the ``W``
+    lanes.  An unbound parameter is an ``ExecError``."""
+    argmap: Dict[int, Any] = {}
+    for p in fn.params:
+        if p.ty is Ty.PTR:
+            if p.name not in buffers:
+                raise ExecError(f"no buffer bound for {p.name}")
+            argmap[id(p)] = buffers[p.name]
+        else:
+            v = scalar_args.get(p.name)
+            if v is None:
+                raise ExecError(f"no scalar bound for {p.name}")
+            argmap[id(p)] = np.full(W, v, dtype=_TY_DTYPE[p.ty])
+    return argmap
+
+
 def launch(module_fn: Function, buffers: Dict[str, np.ndarray],
            params: LaunchParams,
            scalar_args: Optional[Dict[str, Any]] = None,
            globals_mem: Optional[Dict[str, np.ndarray]] = None,
            *, decoded: bool = True, batched: bool = True,
-           ride_along: bool = True,
-           grid: Optional[bool] = None,
-           jax: Optional[Any] = None,
+           grid: bool = True,
            deadline_t: Optional[float] = None,
            deadline_ms: Optional[float] = None,
            mem_budget: Optional[int] = None,
@@ -4505,31 +4479,21 @@ def launch(module_fn: Function, buffers: Dict[str, np.ndarray],
 
     ``decoded=True`` (default) runs the pre-decoded table-driven executor;
     ``decoded=False`` keeps the original instruction-at-a-time loop — the
-    semantics oracle the parity tests and benchmarks/interp_speed.py
-    compare against.  ``batched=True`` (default) additionally runs
+    semantics oracle the parity tests compare against.  ``batched=True``
+    (default) additionally runs
     multi-warp workgroups through the workgroup-batched lockstep executor
     (one (n_warps, W) node walk per workgroup while the warps agree on
     control flow, transparent per-warp fallback otherwise) and packs
     eligible grids — single-warp AND multi-warp workgroups — into
     (n_wg x n_warps, W) grid-level batches with per-workgroup barrier
     groups; both engage only when ``decoded`` is on and OOB-load checking
-    is off.  ``grid`` pins the grid-level batcher: ``True`` attempts it
-    even when ``ride_along`` is off, ``False`` never engages it (the
-    per-workgroup dispatch the benchmarks baseline against), ``None``
-    (default) engages it whenever the launch is eligible.
-    ``ride_along=False`` disables the vx_pred-loop ride-along and (unless
-    ``grid=True``) grid-level batching (the PR 2 executor, kept as a
-    benchmark baseline).
+    is off.  ``grid=True`` (default) engages the grid-level batcher
+    whenever the launch is eligible; ``grid=False`` never engages it
+    (the per-workgroup dispatch of the runtime's "wg" rung).
 
-    ``jax`` engages the JAX codegen rung (core/backends/jaxgen.py) ABOVE
-    grid batching: ``True`` makes a jax-rung failure an ``EngineFault``
-    (the runtime chain demotes it), ``"fallback"`` silently falls
-    through to the normal executor selection, ``None`` (default) never
-    engages it.  The rung self-licenses (order-free + store-private +
-    supported ops) and self-certifies (a differential pass against the
-    normal chain per (kernel, launch shape class), recorded via
-    ``JAX_CERT_HOOKS``); unlicensed or uncertified launches fall
-    through.
+    The JAX codegen rung is not an executor of this function: the
+    runtime's chain runs it above this one (``runtime.interp_launch``,
+    core/backends/jaxgen.py).
 
     Error taxonomy (docs/robustness.md): semantic kernel errors raise
     ``ExecError`` (a ``faults.KernelFault``), annotated with kernel /
@@ -4562,8 +4526,8 @@ def launch(module_fn: Function, buffers: Dict[str, np.ndarray],
     try:
         return _launch_impl(fn, buffers, params, scalar_args,
                             globals_mem, stats=stats, decoded=decoded,
-                            batched=batched, ride_along=ride_along,
-                            grid=grid, jax=jax, mem_budget=mem_budget,
+                            batched=batched, grid=grid,
+                            mem_budget=mem_budget,
                             pool=pool, workers=par_n,
                             par_backend=par_backend)
     except ExecError as e:
@@ -4591,9 +4555,7 @@ def _launch_impl(module_fn: Function, buffers: Dict[str, np.ndarray],
                  globals_mem: Optional[Dict[str, np.ndarray]] = None,
                  *, stats: Optional[ExecStats] = None,
                  decoded: bool = True, batched: bool = True,
-                 ride_along: bool = True,
-                 grid: Optional[bool] = None,
-                 jax: Optional[Any] = None,
+                 grid: bool = True,
                  mem_budget: Optional[int] = None,
                  pool: Optional[DevicePool] = None,
                  workers: int = 1,
@@ -4611,45 +4573,9 @@ def _launch_impl(module_fn: Function, buffers: Dict[str, np.ndarray],
     # launch-invariant pieces, hoisted out of the grid loops: kernel
     # argument vectors and the constant CSR-backed intrinsics (all arrays
     # are read-only to the executors)
-    argmap: Dict[int, Any] = {}
-    for p in fn.params:
-        if p.ty is Ty.PTR:
-            if p.name in buffers:
-                argmap[id(p)] = buffers[p.name]
-            else:
-                raise ExecError(f"no buffer bound for {p.name}")
-        else:
-            v = scalar_args.get(p.name)
-            if v is None:
-                raise ExecError(f"no scalar bound for {p.name}")
-            argmap[id(p)] = np.full(W, v, dtype=_TY_DTYPE[p.ty])
+    argmap = kernel_args(fn, buffers, scalar_args, W)
 
-    if (jax and decoded and batched and n_wg > 1
-            and not params.strict_oob_loads):
-        # jax codegen rung (core/backends/jaxgen.py): licence-gated,
-        # certification-gated.  orchestrate() returns True only when it
-        # produced this launch's results (jitted primary, or a
-        # differential certification run that drove the normal chain
-        # itself); anything it cannot take falls through unchanged.
-        LAST_EXECUTOR[0] = "jax"
-        _faults.push_rung("jax")
-        from .backends import jaxgen as _jaxgen
-
-        def _run_normal(st: ExecStats) -> None:
-            _launch_impl(fn, buffers, params, scalar_args, globals_mem,
-                         stats=st, decoded=decoded, batched=batched,
-                         ride_along=ride_along, grid=grid, jax=None,
-                         mem_budget=mem_budget, pool=pool,
-                         workers=workers, par_backend=par_backend)
-
-        if _jaxgen.orchestrate(fn, buffers, params, scalar_args, mem,
-                               argmap, stats,
-                               "fallback" if jax == "fallback" else True,
-                               _run_normal, route=(jax == "route")):
-            return stats
-
-    want_grid = ride_along if grid is None else grid
-    eligible = bool(decoded and batched and want_grid
+    eligible = bool(decoded and batched and grid
                     and n_wg > 1 and not params.strict_oob_loads)
     if eligible:
         # a crash inside the gate itself is a grid-rung engine fault
@@ -4671,8 +4597,7 @@ def _launch_impl(module_fn: Function, buffers: Dict[str, np.ndarray],
     _faults.push_rung(rung_label)
     prog = _decode(fn, W, params.strict_oob_loads) \
         if decoded and not use_batched and not use_grid else None
-    bprog = _decode_batched(fn, W, params.strict_oob_loads, n_warps,
-                            ride_along=ride_along) \
+    bprog = _decode_batched(fn, W, params.strict_oob_loads, n_warps) \
         if use_batched else None
     base_intr = {
         ("local_size", 0): np.full(W, params.local_size, np.int32),
@@ -4857,7 +4782,6 @@ def _launch_impl(module_fn: Function, buffers: Dict[str, np.ndarray],
                 if nc not in plans:
                     gp = _decode_batched(fn, W, params.strict_oob_loads,
                                          nc * n_warps, grid_mode=True,
-                                         ride_along=ride_along,
                                          wg_rows=n_warps)
                     if not (gp.private_stores if shape_1d
                             else gp.private_stores_2d):
@@ -4950,7 +4874,6 @@ def _launch_impl(module_fn: Function, buffers: Dict[str, np.ndarray],
                 nc = min(wg_chunk, n_wg - c0)
                 gprog = _decode_batched(fn, W, params.strict_oob_loads,
                                         nc * n_warps, grid_mode=True,
-                                        ride_along=ride_along,
                                         wg_rows=n_warps)
                 runahead = (gprog.private_stores if shape_1d
                             else gprog.private_stores_2d)

@@ -47,9 +47,8 @@ statistics:
 Every path returns bit-identical counts to the ``np.unique`` reference
 (property-tested against it across random masks, strides, dtypes and
 OOB-clipped indices).  ``reference_counting()`` switches the whole
-engine back to the historical per-access ``np.unique`` implementation —
-the baseline ``benchmarks/interp_speed.py`` ``interp_speed_mem``
-measures against, and a differential oracle for the parity tests.
+engine back to the historical per-access ``np.unique`` implementation,
+a differential oracle for the parity tests.
 """
 from __future__ import annotations
 

@@ -36,8 +36,8 @@ from .. import graph
 class AnalysisManager:
     """Version-keyed memoization of per-function analyses.
 
-    ``enabled=False`` turns every query into a plain recompute — used by
-    benchmarks/compile_time.py to measure the pre-cache baseline.
+    ``enabled=False`` turns every query into a plain recompute
+    (``run_pipeline(use_analysis_cache=False)``).
     """
 
     def __init__(self, *, enabled: bool = True) -> None:

@@ -24,32 +24,26 @@ spawns the grid (here: schedules the interpreter or the JAX backend).
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import os
-import pickle
-import re
-import tempfile
 import threading
 from collections import Counter, deque
 from dataclasses import dataclass, field
-from pathlib import Path
 from time import perf_counter
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from . import diskcache as _diskcache
 from . import faults as _faults
 from . import governor as _gov
 from . import interp as _interp
 from . import parallel as _parallel
 from .faults import DeadlineExceeded, EngineBusy, EngineFault, KernelFault
-from .interp import ExecError, ExecStats, LaunchParams, \
-    launch as interp_launch
+from .interp import ExecStats, LaunchParams
 from .passes.pipeline import CompiledKernel, PassConfig, run_pipeline
-from .passes.uniformity import UniformityInfo
 from .simx import CycleModel
 from .spans import span
-from .vir import Function, Module, Op, Ty
+from .vir import Function, Module, Ty
 
 _TY_DTYPE = {Ty.I32: np.int32, Ty.F32: np.float32, Ty.BOOL: np.bool_}
 
@@ -62,86 +56,13 @@ _TY_DTYPE = {Ty.I32: np.int32, Ty.F32: np.float32, Ty.BOOL: np.bool_}
 #   * in-memory, keyed by (handle identity, PassConfig fields, warp size);
 #     values keep a strong reference to the handle so its id() can never
 #     be recycled;
-#   * on disk, keyed by (CONTENT hash of the normalized pre-pipeline IR,
-#     PassConfig fields, warp size, schema version) — a second process
-#     compiling an identical kernel deserializes the compiled module
-#     instead of re-running the pass pipeline.  Any change to the kernel
-#     body changes the IR hash, so stale entries can never be returned;
-#     unreadable/corrupt entries fall back to a fresh compile.
-#
-# Disk location: $VOLT_CACHE_DIR, else <checkout>/.cache/volt.  Disable
-# with VOLT_DISK_CACHE=0.
+#   * on disk (``.vck``, core/diskcache.py), keyed by the CONTENT hash of
+#     the normalized pre-pipeline IR — a second process compiling an
+#     identical kernel deserializes the compiled module instead of
+#     re-running the pass pipeline.
 # --------------------------------------------------------------------------
 
 _COMPILE_CACHE: Dict[Tuple, Tuple[Any, CompiledKernel]] = {}
-
-_DISK_CACHE_SCHEMA = 1
-#: telemetry for benchmarks/tests: process-lifetime disk cache counters
-#: (compile-cache hits/misses/errors + decode-plan-cache counterparts)
-DISK_CACHE_STATS = {"hits": 0, "misses": 0, "errors": 0,
-                    "decode_hits": 0, "decode_misses": 0,
-                    "decode_errors": 0,
-                    "cert_hits": 0, "cert_misses": 0, "cert_errors": 0}
-
-_TOKEN_RE = re.compile(r"%[A-Za-z_][\w.]*")
-
-
-def _normalize_ir(dump: str) -> str:
-    """Rewrite process-dependent SSA/label tokens (%v123, %for.cond.17,
-    %gid, ...) to dense first-appearance indices.  The renaming is
-    INJECTIVE within one dump — distinct registers stay distinct — so
-    operand swaps or retargeted branches still change the hash, while
-    identical kernels built in fresh processes (different absolute id
-    counters) normalize to the same text.  Float constants never follow
-    a '%', so they survive untouched."""
-    mapping: Dict[str, str] = {}
-
-    def renum(m: "re.Match[str]") -> str:
-        tok = m.group(0)
-        new = mapping.get(tok)
-        if new is None:
-            new = f"%t{len(mapping)}"
-            mapping[tok] = new
-        return new
-
-    return _TOKEN_RE.sub(renum, dump)
-
-
-def _compiler_fingerprint() -> str:
-    """Hash of the compiler's own source (passes + IR + front-ends):
-    folded into every disk-cache key so editing the pipeline invalidates
-    entries compiled by the old code."""
-    global _COMPILER_FP
-    if _COMPILER_FP is None:
-        h = hashlib.sha256()
-        root = Path(__file__).resolve().parent
-        files = sorted((root / "passes").glob("*.py")) \
-            + sorted((root / "frontends").glob("*.py")) \
-            + [root / "vir.py", root / "graph.py"]
-        for f in files:
-            try:
-                h.update(f.name.encode())
-                h.update(f.read_bytes())
-            except OSError:
-                pass
-        _COMPILER_FP = h.hexdigest()
-    return _COMPILER_FP
-
-
-_COMPILER_FP: Optional[str] = None
-
-
-#: caches the program keeps inside its own checkout (git ignores it)
-CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".cache"
-
-
-def disk_cache_dir() -> Optional[Path]:
-    if os.environ.get("VOLT_DISK_CACHE", "1") == "0":
-        return None
-    d = os.environ.get("VOLT_CACHE_DIR")
-    if d:
-        return Path(d)
-    return CHECKOUT_CACHE / "volt"
 
 
 def use_jax_compile_cache() -> None:
@@ -154,88 +75,7 @@ def use_jax_compile_cache() -> None:
         return
     import jax
     jax.config.update("jax_compilation_cache_dir",
-                      str(CHECKOUT_CACHE / "jax"))
-
-
-def _disk_key(module: Module, kernel_name: str, config: PassConfig,
-              warp_size: int) -> str:
-    h = hashlib.sha256()
-    h.update(repr((_DISK_CACHE_SCHEMA, _compiler_fingerprint(),
-                   kernel_name, dataclasses.astuple(config),
-                   warp_size)).encode())
-    h.update(_normalize_ir(module.dump()).encode())
-    return h.hexdigest()
-
-
-def _freeze_info(module: Module, info: UniformityInfo) -> Tuple:
-    """id()-keyed divergence sets -> object lists (ids do not survive
-    pickling; the objects do, with referential integrity)."""
-    id2obj: Dict[int, Any] = {}
-    for fn in module.functions.values():
-        for b in fn.blocks:
-            id2obj[id(b)] = b
-            for i in b.instrs:
-                id2obj[id(i)] = i
-                if i.result is not None:
-                    id2obj[id(i.result)] = i.result
-                for o in i.operands:
-                    id2obj[id(o)] = o
-        for s in fn.slots:
-            id2obj[id(s)] = s
-    return tuple([id2obj[x] for x in ids if x in id2obj] for ids in (
-        info.divergent_values, info.divergent_slots,
-        info.divergent_exec, info.divergent_branches))
-
-
-def _thaw_info(frozen: Tuple) -> UniformityInfo:
-    dv, ds, de, db = frozen
-    return UniformityInfo({id(o) for o in dv}, {id(o) for o in ds},
-                          {id(o) for o in de}, {id(o) for o in db})
-
-
-def _atomic_write(path: Path, payload: bytes) -> None:
-    """Crash-safe cache write, shared by the compile cache (.vck) and
-    the decode-plan cache (.vdp): the payload lands in a same-directory
-    tmp file, then ``os.replace`` commits it atomically — a crash (or
-    an injected ``cache.commit`` fault) before the rename leaves only
-    tmp debris, NEVER a truncated entry a concurrent reader could
-    deserialize."""
-    path.parent.mkdir(parents=True, exist_ok=True)
-    fd, tmp = tempfile.mkstemp(dir=str(path.parent), suffix=".tmp")
-    with os.fdopen(fd, "wb") as f:
-        f.write(payload)
-    if _faults.ACTIVE:
-        _faults.maybe_fault("cache.commit")
-    os.replace(tmp, path)
-
-
-def _disk_load(path: Path, kernel_name: str,
-               config: PassConfig) -> Optional[CompiledKernel]:
-    try:
-        if _faults.ACTIVE:
-            _faults.maybe_fault("cache.load")
-        with open(path, "rb") as f:
-            module, frozen, stats = pickle.load(f)
-        return CompiledKernel(module, module.functions[kernel_name],
-                              _thaw_info(frozen), config, stats)
-    except Exception:
-        DISK_CACHE_STATS["errors"] += 1
-        try:
-            path.unlink()
-        except OSError:
-            pass
-        return None
-
-
-def _disk_store(path: Path, ck: CompiledKernel) -> None:
-    try:
-        if _faults.ACTIVE:
-            _faults.maybe_fault("cache.store")
-        payload = pickle.dumps(
-            (ck.module, _freeze_info(ck.module, ck.info), ck.stats))
-        _atomic_write(path, payload)
-    except Exception:              # cache write failure never fails a
-        DISK_CACHE_STATS["errors"] += 1   # compile
+                      str(_diskcache.CHECKOUT_CACHE / "jax"))
 
 
 def compile_kernel(kernel_handle, config: Optional[PassConfig] = None,
@@ -252,24 +92,18 @@ def compile_kernel(kernel_handle, config: Optional[PassConfig] = None,
         if hit is not None:
             return hit[1]
     module = kernel_handle.build(None)
-    cache_dir = disk_cache_dir() if use_disk_cache in (None, True) else None
-    if use_disk_cache is False:
-        cache_dir = None
-    path = None
-    if cache_dir is not None:
-        path = Path(cache_dir) / (_disk_key(module, kernel_handle.name,
-                                            config, warp_size) + ".vck")
-        if path.exists():
-            ck = _disk_load(path, kernel_handle.name, config)
-            if ck is not None:
-                DISK_CACHE_STATS["hits"] += 1
-                if use_cache:
-                    _COMPILE_CACHE[key] = (kernel_handle, ck)
-                return ck
-        DISK_CACHE_STATS["misses"] += 1
+    disk_key = None
+    if use_disk_cache is not False and _diskcache.cache_dir() is not None:
+        disk_key = _diskcache.kernel_key(module, kernel_handle.name,
+                                         config, warp_size)
+        ck = _diskcache.load_kernel(disk_key, kernel_handle.name, config)
+        if ck is not None:
+            if use_cache:
+                _COMPILE_CACHE[key] = (kernel_handle, ck)
+            return ck
     ck = run_pipeline(module, kernel_handle.name, config)
-    if path is not None:
-        _disk_store(path, ck)
+    if disk_key is not None:
+        _diskcache.store_kernel(disk_key, ck)
     if use_cache:
         _COMPILE_CACHE[key] = (kernel_handle, ck)
     return ck
@@ -278,184 +112,7 @@ def compile_kernel(kernel_handle, config: Optional[PassConfig] = None,
 def clear_compile_cache(*, disk: bool = False) -> None:
     _COMPILE_CACHE.clear()
     if disk:
-        d = disk_cache_dir()
-        if d is not None and Path(d).exists():
-            for p in list(Path(d).glob("*.vck")) \
-                    + list(Path(d).glob("*.vdp")):
-                try:
-                    p.unlink()
-                except OSError:
-                    pass
-
-
-# --------------------------------------------------------------------------
-# Persistent decode-plan cache (the PR 3 follow-up): the interpreter's
-# per-function decode ANALYSIS (affine index facts, store privacy,
-# hazard/cyclic classification, callee purity — see interp._decode_plan)
-# persists next to the compile cache, keyed by a content hash of the
-# function plus its transitive callees and referenced globals.  The
-# decoded handler tables themselves are closures and never persist —
-# a second process still emits handlers, but skips every static scan.
-# Stale entries are impossible (any IR edit changes the hash; the
-# fingerprint below folds in the decoder's own source); corrupt entries
-# are deleted and recomputed.  Shares $VOLT_CACHE_DIR / VOLT_DISK_CACHE
-# with the compile cache; hit counts land in DISK_CACHE_STATS
-# (decode_hits / decode_misses / decode_errors, reported by
-# benchmarks/compile_time.py).
-# --------------------------------------------------------------------------
-
-_DECODE_PLAN_FP: Optional[str] = None
-
-
-def _decode_plan_fingerprint() -> str:
-    """Hash of the decoder's own source: editing the interpreter, the
-    coalescing engine or the affine classifier invalidates plans
-    computed by the old code."""
-    global _DECODE_PLAN_FP
-    if _DECODE_PLAN_FP is None:
-        h = hashlib.sha256()
-        root = Path(__file__).resolve().parent
-        for f in (root / "interp.py", root / "interp_mem.py",
-                  root / "vir.py", root / "passes" / "analysis.py"):
-            try:
-                h.update(f.name.encode())
-                h.update(f.read_bytes())
-            except OSError:
-                pass
-        _DECODE_PLAN_FP = h.hexdigest()
-    return _DECODE_PLAN_FP
-
-
-def _decode_plan_key(fn: Function) -> str:
-    """Content hash of ``fn`` + transitive callees + referenced globals
-    (name/space/size matter: __shared__-ness changes hazard rules)."""
-    cached = getattr(fn, "_decode_plan_key", None)
-    if cached is not None and cached[0] == fn.ir_version:
-        return cached[1]
-    h = hashlib.sha256()
-    h.update(repr((_interp._DECODE_PLAN_SCHEMA,
-                   _decode_plan_fingerprint())).encode())
-    seen = set()
-    work = [fn]
-    gvars = []
-    while work:
-        f = work.pop(0)
-        if id(f) in seen:
-            continue
-        seen.add(id(f))
-        h.update(_normalize_ir(f.dump()).encode())
-        for i in f.instructions():
-            if i.op is Op.CALL:
-                work.append(i.operands[0])
-            for o in i.operands:
-                if o.__class__.__name__ == "GlobalVar":
-                    gvars.append((o.name, str(o.space), o.size,
-                                  str(o.elem_ty)))
-    h.update(repr(sorted(set(gvars))).encode())
-    key = h.hexdigest()
-    fn._decode_plan_key = (fn.ir_version, key)  # type: ignore
-    return key
-
-
-def _decode_plan_load(fn: Function) -> Optional[dict]:
-    d = disk_cache_dir()
-    if d is None:
-        return None
-    path = Path(d) / (_decode_plan_key(fn) + ".vdp")
-    if not path.exists():
-        DISK_CACHE_STATS["decode_misses"] += 1
-        return None
-    try:
-        if _faults.ACTIVE:
-            _faults.maybe_fault("plan.load")
-        with open(path, "rb") as f:
-            plan = pickle.load(f)
-        if plan.get("schema") != _interp._DECODE_PLAN_SCHEMA:
-            raise ValueError("decode plan schema mismatch")
-        DISK_CACHE_STATS["decode_hits"] += 1
-        return plan
-    except Exception:
-        DISK_CACHE_STATS["decode_errors"] += 1
-        try:
-            path.unlink()
-        except OSError:
-            pass
-        return None
-
-
-def _decode_plan_save(fn: Function, plan: dict) -> None:
-    d = disk_cache_dir()
-    if d is None:
-        return
-    try:
-        if _faults.ACTIVE:
-            _faults.maybe_fault("plan.store")
-        path = Path(d) / (_decode_plan_key(fn) + ".vdp")
-        _atomic_write(path, pickle.dumps(plan))
-    except Exception:              # plan persistence is best-effort
-        DISK_CACHE_STATS["decode_errors"] += 1
-
-
-_interp.DECODE_PLAN_HOOKS = (_decode_plan_load, _decode_plan_save)
-
-# schema 2: verdicts gained the "pass-exact" tier — a schema-1 "pass"
-# meant "certified at backend level 0" and must not promote a pair onto
-# the optimized fast tier, so old files are discarded wholesale.
-# schema 3: verdicts carry measured (jax_ms, grid_ms) per launch-shape
-# class so the dispatch router can send small launches straight to the
-# grid rung (the ~0.5 ms jitted-dispatch floor fix); schema-2 verdicts
-# lack the timings and are discarded wholesale.
-# schema 4: each verdict carries a detail (the first difference from the
-# oracle, or the exception a tier raised), and its shape signature names
-# the device it was certified on (platform, device_kind, JAX version)
-_JAX_CERT_SCHEMA = 4
-
-
-def _jax_cert_load(fn: Function) -> Optional[dict]:
-    """.vjc read: the jax rung's differential-certification verdicts
-    ({launch-shape-sig: (verdict, jax_ms, grid_ms, detail)}), keyed by the
-    same kernel content hash as the .vck/.vdp caches — an IR change
-    invalidates every verdict with it."""
-    d = disk_cache_dir()
-    if d is None:
-        return None
-    path = Path(d) / (_decode_plan_key(fn) + ".vjc")
-    if not path.exists():
-        DISK_CACHE_STATS["cert_misses"] += 1
-        return None
-    try:
-        with open(path, "rb") as f:
-            rec = pickle.load(f)
-        if rec.get("schema") != _JAX_CERT_SCHEMA:
-            raise ValueError("jax cert schema mismatch")
-        certs = rec["certs"]
-        if not isinstance(certs, dict):
-            raise ValueError("jax cert payload is not a dict")
-        DISK_CACHE_STATS["cert_hits"] += 1
-        return certs
-    except Exception:
-        DISK_CACHE_STATS["cert_errors"] += 1
-        try:
-            path.unlink()
-        except OSError:
-            pass
-        return None
-
-
-def _jax_cert_save(fn: Function, certs: dict) -> None:
-    d = disk_cache_dir()
-    if d is None:
-        return
-    try:
-        path = Path(d) / (_decode_plan_key(fn) + ".vjc")
-        _atomic_write(path, pickle.dumps(
-            {"schema": _JAX_CERT_SCHEMA, "certs": certs}))
-    except Exception:              # cert persistence is best-effort
-        DISK_CACHE_STATS["cert_errors"] += 1
-
-
-_interp.JAX_CERT_HOOKS = (_jax_cert_load, _jax_cert_save)
-_interp.ROUTED_SMALL_HOOK = lambda: _tel("routed_small")
+        _diskcache.clear()
 
 
 @dataclass
@@ -467,7 +124,7 @@ class Buffer:
 # --------------------------------------------------------------------------
 # Executor degradation chain (docs/robustness.md).
 #
-# The four executors form rungs of a ladder, fastest first; an
+# The executors form rungs of a ladder, fastest first; an
 # ``EngineFault`` (internal fast-path failure — injected or real)
 # demotes the launch to the rung BELOW the executor that actually ran,
 # after rolling written buffers back to their pre-launch snapshot, so a
@@ -479,23 +136,70 @@ class Buffer:
 
 _RUNG_ORDER = ("jax", "grid", "wg", "decoded", "oracle")
 
-#: interp.launch kwargs per rung.  "jax" is the top rung when the
-#: Runtime enables it (jax=True / VOLT_JAX=1): the jitted-codegen
-#: executor, auto-falling through to grid selection when the licence or
-#: certification gate refuses.  The chain asks for ``jax="route"`` —
-#: like True, plus the small-launch dispatch router: certified pairs
-#: whose measured grid time beats the jitted dispatch floor are served
-#: by the grid rung (docs/performance.md "Serve side").  "grid" is the
-#: production default (auto-selects grid / wg-batched / decoded by
-#: eligibility); pinning grid=False / batched=False peels one fast
-#: path per rung.
+#: interp.launch kwargs of the host rungs.  "grid" is the production
+#: default (auto-selects grid / wg-batched / decoded by eligibility);
+#: pinning grid=False / batched=False peels one fast path per rung.
+#: "jax", the top rung when the Runtime enables it (jax=True /
+#: VOLT_JAX=1), is no mode of interp.launch: ``interp_launch`` runs it.
 _RUNG_KWARGS: Dict[str, Dict[str, Any]] = {
-    "jax":     dict(decoded=True, batched=True, jax="route"),
     "grid":    dict(decoded=True, batched=True),
     "wg":      dict(decoded=True, batched=True, grid=False),
     "decoded": dict(decoded=True, batched=False),
     "oracle":  dict(decoded=False, batched=False),
 }
+
+
+def interp_launch(fn: Function, buffers: Dict[str, np.ndarray],
+                  params: LaunchParams,
+                  scalar_args: Optional[Dict[str, Any]] = None, *,
+                  rung: str = "grid",
+                  globals_mem: Optional[Dict[str, np.ndarray]] = None,
+                  deadline_t: Optional[float] = None,
+                  deadline_ms: Optional[float] = None,
+                  mem_budget: Optional[int] = None,
+                  pool: Optional[_interp.DevicePool] = None,
+                  workers: Optional[object] = None) -> ExecStats:
+    """One attempt of one rung of the chain; every attempt of
+    ``Runtime.launch`` goes through this one name.  A host rung is
+    ``interp.launch`` under its ``_RUNG_KWARGS``.  The "jax" rung asks
+    ``jaxgen.serve`` first, which either serves the launch (the jitted
+    program as the certified primary, or a certification run whose
+    results come from the grid rung) or declines it; a declined launch
+    runs on the grid rung in the same attempt.  The executor that
+    served is left in ``interp.LAST_EXECUTOR[0]``."""
+    kw = dict(globals_mem=globals_mem, mem_budget=mem_budget, pool=pool,
+              workers=workers)
+    if rung != "jax":
+        return _interp.launch(fn, buffers, params, scalar_args,
+                              deadline_t=deadline_t,
+                              deadline_ms=deadline_ms, **kw,
+                              **_RUNG_KWARGS[rung])
+    from .backends import jaxgen   # the numpy chain never loads JAX
+    if deadline_t is None and deadline_ms is not None:
+        # one absolute deadline for the rung and a grid run after it
+        deadline_t = perf_counter() + deadline_ms * 1e-3
+    kw.update(deadline_t=deadline_t, deadline_ms=deadline_ms,
+              **_RUNG_KWARGS["grid"])
+
+    def run_grid(stats: ExecStats) -> None:
+        stats.merge(_interp.launch(fn, buffers, params, scalar_args,
+                                   **kw))
+
+    stats = ExecStats()
+    if deadline_t is not None:
+        _gov.arm_deadline(deadline_t, deadline_ms, stats)
+    try:
+        with _faults.rung("jax"):
+            outcome = jaxgen.serve(fn, buffers, params, scalar_args or {},
+                                   globals_mem, stats, run_grid)
+    finally:
+        if deadline_t is not None:
+            _gov.disarm_deadline()
+    if outcome == "served":
+        return stats
+    if outcome == "routed":
+        _tel("routed_small")
+    return _interp.launch(fn, buffers, params, scalar_args, **kw)
 
 
 @dataclass
@@ -558,10 +262,9 @@ def _attach_report(e: BaseException, report: LaunchReport) -> None:
 
 #: process-lifetime launch/degradation counters (GRID_TELEMETRY's
 #: pattern: NOT part of ExecStats — stats stay bit-identical across
-#: executors by contract).  Printed by ``benchmarks/run.py --profile``.
-#: Mutate through ``_tel``/``_tel_ctr`` — the launch service drains
-#: queues from concurrent submitter threads, and bare ``+=`` on a module
-#: dict is a read-modify-write race.
+#: executors by contract).  Mutate through ``_tel``/``_tel_ctr`` — the
+#: launch service drains queues from concurrent submitter threads, and
+#: bare ``+=`` on a module dict is a read-modify-write race.
 LAUNCH_TELEMETRY: Dict[str, Any] = {}
 
 _TEL_LOCK = threading.Lock()
@@ -603,11 +306,13 @@ class Runtime:
     ``EngineFault`` in a fast path rolls written buffers back to their
     pre-launch snapshot and retries one rung down (jax-codegen when
     enabled -> grid -> wg-batched -> decoded -> oracle), recording
-    every attempt in
-    ``self.last_report``.  ``transactional=False`` disables the
-    write-root snapshots — and with them the chain, since retrying over
-    partially-committed stores (or re-applied atomics) would be unsound;
-    an EngineFault then surfaces to the caller.
+    every attempt in ``self.last_report``.  Each attempt is one call of
+    ``interp_launch``; a jax attempt the rung declines (licence, router
+    or verdict) is served by the grid rung within that attempt.
+    ``transactional=False`` disables the write-root snapshots — and with
+    them the chain, since retrying over partially-committed stores (or
+    re-applied atomics) would be unsound; an EngineFault then surfaces
+    to the caller.
 
     ``govern=True`` (default) arms the launch governor
     (core/governor.py, docs/robustness.md): per-launch wall-clock
@@ -730,9 +435,9 @@ class Runtime:
         """Transactional snapshot: copy the buffers this kernel may
         WRITE (interp.write_root_buffers; everything bound when the
         scan cannot resolve a store root).  Read-only buffers are never
-        copied — that is what keeps the clean-path overhead inside the
-        <5% bench_robust budget.  Also records the global names alive
-        now, so a rollback can drop globals the launch lazily created.
+        copied, which keeps the clean path cheap.  Also records the
+        global names alive now, so a rollback can drop globals the
+        launch lazily created.
 
         With a memory ``budget``, an over-budget snapshot is refused
         (returns None) and the caller degrades to oracle-first
@@ -849,7 +554,7 @@ class Runtime:
         bkey: Optional[str] = None
         probing = False
         if self.breaker is not None and len(chain) > 1:
-            bkey = _decode_plan_key(kernel_fn)
+            bkey = _diskcache.plan_key(kernel_fn)
             pin, probing = self.breaker.plan(bkey, kernel_fn.name)
             report.breaker = self.breaker.entry(
                 bkey, kernel_fn.name).state
@@ -888,13 +593,13 @@ class Runtime:
             try:
                 stats = interp_launch(kernel_fn, bufs, params,
                                       scalar_args=scalar_args,
+                                      rung=rung,
                                       globals_mem=gmem,
                                       deadline_t=deadline_t,
                                       deadline_ms=deadline_ms,
                                       mem_budget=mem_budget,
                                       pool=self.pool,
-                                      workers=self.workers,
-                                      **_RUNG_KWARGS[rung])
+                                      workers=self.workers)
             except DeadlineExceeded as e:
                 used = _interp.LAST_EXECUTOR[0] or rung
                 report.attempts.append(LaunchAttempt(
@@ -1179,7 +884,7 @@ class LaunchService:
                 sig.append((p.name, b.shape, b.dtype.str))
             else:
                 sig.append((p.name, None, None))
-        return (_decode_plan_key(fn), block, self.rt.warp_size,
+        return (_diskcache.plan_key(fn), block, self.rt.warp_size,
                 tuple(sig))
 
     def _run_group(self, key: Tuple[Any, ...],

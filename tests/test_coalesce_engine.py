@@ -37,6 +37,7 @@ from repro.core.passes.pipeline import ABLATION_LADDER, run_pipeline
 from repro.core.vir import Op
 from repro.volt_bench import BENCHES
 
+import rungs
 import volt_kernels as K
 
 FULL = ABLATION_LADDER[-1]
@@ -253,7 +254,7 @@ EXECUTORS = {
     "decoded": dict(decoded=True, batched=False),
     "wg_batched": dict(decoded=True, batched=True, grid=False),
     "grid": dict(decoded=True, batched=True, grid=True),
-    "jax": dict(decoded=True, batched=True, grid=True, jax="fallback"),
+    "jax": rungs.JAX,
 }
 
 
@@ -277,14 +278,14 @@ def test_oob_clip_rule_consistent_across_executors():
             if label == "jax":        # certification warm-up launch
                 jaxgen.reset_jax_telemetry()
                 bufs = {k: v.copy() for k, v in bufs0.items()}
-                interp.launch(fn, bufs, p, scalar_args=sc, **kw)
+                rungs.launch(fn, bufs, p, scalar_args=sc, **kw)
             bufs = {k: v.copy() for k, v in bufs0.items()}
-            stats[label] = _stats_tuple(interp.launch(
+            stats[label] = _stats_tuple(rungs.launch(
                 fn, bufs, p, scalar_args=sc, **kw))
             bufs = {k: v.copy() for k, v in bufs0.items()}
             with interp_mem.reference_counting():
-                ref = _stats_tuple(interp.launch(fn, bufs, p,
-                                                 scalar_args=sc, **kw))
+                ref = _stats_tuple(rungs.launch(fn, bufs, p,
+                                                scalar_args=sc, **kw))
             assert ref == stats[label], \
                 f"{label} x{factor}: counting mode changed ExecStats"
         assert jaxgen.JAX_TELEMETRY["engaged"] >= 1, \

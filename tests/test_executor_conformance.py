@@ -26,11 +26,11 @@ per workgroup where the batched path provably falls back to the per-warp
 schedule; the grid-level batcher refuses them via its read-write-hazard
 scan.
 
-The jax column runs with ``jax="fallback"``: the rung self-licenses and
-self-certifies, silently falling through to the normal chain when it
-refuses — so parity holds on EVERY kernel, and a separate ENGAGEMENT
-test (telemetry) proves the rung truly executed each licence-admitted
-kernel rather than vacuously falling back.  Each jax run is preceded by
+The jax column runs through ``rungs.launch`` (tests/kernels/rungs.py):
+the rung self-licenses and self-certifies, and the grid rung serves
+what it declines or faults on — so parity holds on EVERY kernel, and a
+separate ENGAGEMENT test (telemetry) proves the rung truly executed
+each licence-admitted kernel rather than vacuously falling back.  Each jax run is preceded by
 a warm-up launch on scratch buffer copies so the differential
 certification verdict is already recorded and the compared launch is
 the jitted primary.
@@ -53,6 +53,7 @@ from repro.core import interp
 from repro.core.passes.pipeline import ABLATION_LADDER, run_pipeline
 from repro.volt_bench import BENCHES
 
+import rungs
 import volt_kernels as K
 
 FULL = ABLATION_LADDER[-1]
@@ -89,7 +90,7 @@ EXECUTORS = {
     "decoded": dict(decoded=True, batched=False),
     "wg_batched": dict(decoded=True, batched=True, grid=False),
     "grid": dict(decoded=True, batched=True, grid=True),
-    "jax": dict(decoded=True, batched=True, grid=True, jax="fallback"),
+    "jax": rungs.JAX,
 }
 
 
@@ -256,18 +257,18 @@ def _compiled(name: str):
 
 
 def _run_one(fn, bufs0, params, scalars, kw):
-    if "jax" in kw:
+    if kw.get("rung") == "jax":
         # warm-up on scratch copies: the first licensed launch is the
         # differential certification run; after it the recorded verdict
         # lets the compared launch below run as the jitted primary
         warm = {k: v.copy() for k, v in bufs0.items()}
         try:
-            interp.launch(fn, warm, params, scalar_args=scalars, **kw)
+            rungs.launch(fn, warm, params, scalar_args=scalars, **kw)
         except interp.ExecError:
             pass
     bufs = {k: v.copy() for k, v in bufs0.items()}
     try:
-        st = interp.launch(fn, bufs, params, scalar_args=scalars, **kw)
+        st = rungs.launch(fn, bufs, params, scalar_args=scalars, **kw)
     except interp.ExecError as e:
         return ("error", type(e).__name__, None, None)
     return ("ok", None, st, bufs)
@@ -405,8 +406,8 @@ def test_exec_errors_carry_context(label):
     fn = _compiled("tk_saxpy")
     bufs = {k: v.copy() for k, v in bufs0.items()}
     with pytest.raises(interp.ExecError) as ei:
-        interp.launch(fn, bufs, params, scalar_args=scalars,
-                      **EXECUTORS[label])
+        rungs.launch(fn, bufs, params, scalar_args=scalars,
+                     **EXECUTORS[label])
     msg = str(ei.value)
     assert "in @saxpy" in msg, (label, msg)
     assert "workgroup" in msg, (label, msg)

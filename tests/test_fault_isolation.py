@@ -215,7 +215,8 @@ def test_kernel_faults_are_never_retried():
     for label, kw in conf.EXECUTORS.items():
         bufs = {k: v.copy() for k, v in bufs0.items()}
         with pytest.raises(interp.ExecError) as ei:
-            interp.launch(fn, bufs, params, scalar_args=scalars, **kw)
+            conf.rungs.launch(fn, bufs, params, scalar_args=scalars,
+                              **kw)
         errs[label] = ei.value
         assert isinstance(ei.value, faults.KernelFault)
     assert len({type(e).__name__ for e in errs.values()}) == 1
@@ -231,7 +232,8 @@ def test_exec_errors_carry_kernel_and_workgroup_context():
     for label, kw in conf.EXECUTORS.items():
         bufs = {k: v.copy() for k, v in bufs0.items()}
         with pytest.raises(interp.ExecError) as ei:
-            interp.launch(fn, bufs, params, scalar_args=scalars, **kw)
+            conf.rungs.launch(fn, bufs, params, scalar_args=scalars,
+                              **kw)
         msg = str(ei.value)
         assert "in @saxpy" in msg, (label, msg)
         assert "workgroup" in msg, (label, msg)

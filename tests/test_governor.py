@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 import test_executor_conformance as conf
-from repro.core import faults, governor, interp, runtime
+from repro.core import diskcache, faults, governor, interp, runtime
 from repro.core.frontends import opencl
 from repro.core.runtime import (LAUNCH_TELEMETRY, Runtime,
                                 reset_launch_telemetry)
@@ -136,8 +136,8 @@ def test_expired_deadline_raises_in_every_executor(executor):
     params = interp.LaunchParams(grid=2, local_size=64, warp_size=32)
     bufs = {k: v.copy() for k, v in bufs0.items()}
     with pytest.raises(faults.DeadlineExceeded) as ei:
-        interp.launch(fn, bufs, params, scalar_args=scalars,
-                      deadline_ms=0.0, **conf.EXECUTORS[executor])
+        conf.rungs.launch(fn, bufs, params, scalar_args=scalars,
+                          deadline_ms=0.0, **conf.EXECUTORS[executor])
     assert ei.value.deadline_ms == 0.0
     assert ei.value.elapsed_ms is not None
 
@@ -320,8 +320,8 @@ def test_breaker_is_keyed_by_kernel_content():
     with faults.inject("grid.exec"):
         hit()
     fn2 = conf._compiled("transpose")
-    key1 = runtime._decode_plan_key(conf._compiled("vecadd"))
-    key2 = runtime._decode_plan_key(fn2)
+    key1 = diskcache.plan_key(conf._compiled("vecadd"))
+    key2 = diskcache.plan_key(fn2)
     assert key1 != key2
     assert rt.breaker.entries[key1].state == "open"
     assert key2 not in rt.breaker.entries

@@ -53,6 +53,7 @@ from repro.core.backends import jaxgen
 from repro.core.passes.pipeline import ABLATION_LADDER, run_pipeline
 from repro.volt_bench import BENCHES
 
+import rungs
 import volt_kernels as K
 
 FULL = ABLATION_LADDER[-1]
@@ -76,7 +77,7 @@ def _stats_tuple(st: interp.ExecStats):
 
 def _launch(fn, bufs0, params, scalars, **kw):
     bufs = {k: v.copy() for k, v in bufs0.items()}
-    st = interp.launch(fn, bufs, params, scalar_args=scalars, **kw)
+    st = rungs.launch(fn, bufs, params, scalar_args=scalars, **kw)
     return _stats_tuple(st), bufs
 
 
@@ -337,7 +338,7 @@ def test_compaction_needs_private_stores():
 # jax-rung metamorphic sweeps
 # --------------------------------------------------------------------------
 
-_JAX_KW = dict(decoded=True, batched=True, grid=True, jax="fallback")
+_JAX_KW = rungs.JAX
 
 
 def _jax_cases(factor=1):
@@ -357,7 +358,7 @@ def _jax_launch(fn, bufs0, params, sc):
     """Certification warm-up launch + certified primary launch; returns
     the primary's (stats, buffers)."""
     warm = {k: v.copy() for k, v in bufs0.items()}
-    interp.launch(fn, warm, params, scalar_args=sc, **_JAX_KW)
+    rungs.launch(fn, warm, params, scalar_args=sc, **_JAX_KW)
     return _launch(fn, bufs0, params, sc, **_JAX_KW)
 
 

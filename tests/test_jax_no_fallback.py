@@ -13,7 +13,7 @@
 import numpy as np
 import pytest
 
-from repro.core import runtime
+from repro.core import diskcache
 from repro.core.backends import jaxgen
 from repro.core.passes.pipeline import ABLATION_LADDER, run_pipeline
 from repro.core.runtime import Runtime
@@ -132,13 +132,13 @@ def test_verdict_of_another_platform_is_not_used(monkeypatch):
 
     # a new process: the verdict comes back from the .vjc file only
     fn2, bufs2, scalars2, params2, ref2 = _fresh()
-    stored = runtime._jax_cert_load(fn2)
+    stored = diskcache.load_verdicts(fn2)
     assert len(stored) == 1 and repr(other) in next(iter(stored))
     _launch(rt, fn2, bufs2, scalars2, params2)
     assert jaxgen.JAX_TELEMETRY["cert_runs"] == 2
     assert rt.last_report.executor == "grid"     # certification run
     np.testing.assert_array_equal(bufs2["z"], ref2["z"])
-    assert len(runtime._jax_cert_load(fn2)) == 2
+    assert len(diskcache.load_verdicts(fn2)) == 2
     # under its own key the new verdict promotes the launch
     _launch(rt, fn2, bufs2, scalars2, params2)
     assert rt.last_report.executor == "jax"

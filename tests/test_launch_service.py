@@ -32,7 +32,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).parent / "kernels"))
 
-from repro.core import faults, governor, interp, runtime
+from repro.core import diskcache, faults, governor, interp, runtime
 from repro.core.faults import DeadlineExceeded, EngineBusy
 from repro.core.passes.pipeline import ABLATION_LADDER, run_pipeline
 from repro.core.runtime import LaunchService, Runtime
@@ -303,7 +303,7 @@ def test_service_open_breaker_disables_coalescing():
     fn = _compiled(BENCHES["vecadd"].handle)
     rt = Runtime()
     svc = LaunchService(rt)
-    key = runtime._decode_plan_key(fn)
+    key = diskcache.plan_key(fn)
     entry = rt.breaker.entry(key, fn.name)
     entry.state = "open"
     entry.pinned_rung = "decoded"
@@ -521,7 +521,7 @@ def test_mem_budget_aborts_staging_to_solo():
 # small-launch router (jax rung dispatch floor)
 # --------------------------------------------------------------------------
 
-def test_small_launch_router_prefers_grid_when_measured_faster():
+def test_small_launch_router_prefers_grid_when_measured_faster(monkeypatch):
     """Schema-3 verdicts carry measured (jax_ms, grid_ms); when the grid
     walk measured decisively faster, the jax rung declines the launch so
     the ~0.5 ms dispatch floor never taxes small kernels.  Timings are
@@ -540,37 +540,35 @@ def test_small_launch_router_prefers_grid_when_measured_faster():
     certs = jaxgen._certs(fn)
     assert certs, "cert store never populated"
 
-    # pin decisive measurements in-memory only: detach the disk hooks so
-    # the fake timings never reach the shared .vjc store
-    hooks = interp.JAX_CERT_HOOKS
-    interp.JAX_CERT_HOOKS = None
-    try:
-        for sig, entry in list(certs.items()):
-            verdict, jax_ms, grid_ms = jaxgen._verdict_of(entry)
-            assert verdict in ("pass", "pass-exact")
-            assert grid_ms is not None, "cert run did not measure grid_ms"
-            jaxgen._record(fn, sig, verdict, jax_ms=10.0, grid_ms=1.0)
+    # pin decisive measurements in-memory only: the disk cache is off,
+    # so the fake timings never reach the shared .vjc store
+    monkeypatch.setenv("VOLT_DISK_CACHE", "0")
+    for sig, entry in list(certs.items()):
+        verdict, jax_ms, grid_ms = jaxgen._verdict_of(entry)
+        assert verdict in ("pass", "pass-exact")
+        assert grid_ms is not None, "cert run did not measure grid_ms"
+        jaxgen._record(fn, sig, verdict, jax_ms=10.0, grid_ms=1.0)
 
-        before = jaxgen.JAX_TELEMETRY["routed_small"]
-        ref = {k: v.copy() for k, v in bufs.items()}
-        interp.launch(fn, ref, params, scalar_args=scalars)
-        rt.launch(fn, grid=params.grid, block=params.local_size,
-                  scalar_args=scalars, buffers=bufs)
-        assert jaxgen.JAX_TELEMETRY["routed_small"] == before + 1
-        assert rt.last_report.executor == "grid"
-        for k in ref:
-            np.testing.assert_array_equal(ref[k], bufs[k])
+    before = jaxgen.JAX_TELEMETRY["routed_small"]
+    routed = runtime.LAUNCH_TELEMETRY["routed_small"]
+    ref = {k: v.copy() for k, v in bufs.items()}
+    interp.launch(fn, ref, params, scalar_args=scalars)
+    rt.launch(fn, grid=params.grid, block=params.local_size,
+              scalar_args=scalars, buffers=bufs)
+    assert jaxgen.JAX_TELEMETRY["routed_small"] == before + 1
+    assert runtime.LAUNCH_TELEMETRY["routed_small"] == routed + 1
+    assert rt.last_report.executor == "grid"
+    for k in ref:
+        np.testing.assert_array_equal(ref[k], bufs[k])
 
-        # flip the measurement: jax decisively faster -> jax serves again
-        for sig, entry in list(certs.items()):
-            verdict, _, _ = jaxgen._verdict_of(entry)
-            jaxgen._record(fn, sig, verdict, jax_ms=1.0, grid_ms=10.0)
-        rt.launch(fn, grid=params.grid, block=params.local_size,
-                  scalar_args=scalars, buffers=bufs)
-        assert rt.last_report.executor == "jax"
-        assert jaxgen.JAX_TELEMETRY["routed_small"] == before + 1
-    finally:
-        interp.JAX_CERT_HOOKS = hooks
+    # flip the measurement: jax decisively faster -> jax serves again
+    for sig, entry in list(certs.items()):
+        verdict, _, _ = jaxgen._verdict_of(entry)
+        jaxgen._record(fn, sig, verdict, jax_ms=1.0, grid_ms=10.0)
+    rt.launch(fn, grid=params.grid, block=params.local_size,
+              scalar_args=scalars, buffers=bufs)
+    assert rt.last_report.executor == "jax"
+    assert jaxgen.JAX_TELEMETRY["routed_small"] == before + 1
 
 
 # --------------------------------------------------------------------------

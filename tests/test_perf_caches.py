@@ -9,7 +9,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).parent / "kernels"))
 
-from repro.core import graph, interp, runtime
+from repro.core import diskcache, graph, interp, runtime
 from repro.core.vir import Const, Instr, Op, Reg, Ty
 from repro.core.passes.analysis import AnalysisManager
 from repro.core.passes.pipeline import (ABLATION_LADDER, PassConfig,
@@ -227,16 +227,16 @@ def test_decode_plan_disk_cache(tmp_path, monkeypatch):
         mod = b.handle.build(None)
         return run_pipeline(mod, b.handle.name, ABLATION_LADDER[-1]).fn
 
-    base = dict(runtime.DISK_CACHE_STATS)
+    base = dict(diskcache.DISK_CACHE_STATS)
     fn1 = fresh_fn()
     prog1 = interp._decode_batched(fn1, 32, False, 1, grid_mode=True)
-    assert runtime.DISK_CACHE_STATS["decode_misses"] > base["decode_misses"]
-    hits0 = runtime.DISK_CACHE_STATS["decode_hits"]
+    assert diskcache.DISK_CACHE_STATS["decode_misses"] > base["decode_misses"]
+    hits0 = diskcache.DISK_CACHE_STATS["decode_hits"]
     assert list(tmp_path.glob("*.vdp")), "plan must persist to disk"
 
     fn2 = fresh_fn()                 # same content, new objects
     prog2 = interp._decode_batched(fn2, 32, False, 1, grid_mode=True)
-    assert runtime.DISK_CACHE_STATS["decode_hits"] > hits0, \
+    assert diskcache.DISK_CACHE_STATS["decode_hits"] > hits0, \
         "identical kernel must hit the decode-plan cache"
     # loaded-plan decode classifications match the computed ones
     assert (prog1.order_free, prog1.private_stores,
@@ -277,25 +277,25 @@ def test_decode_plan_corrupt_and_content_invalidation(tmp_path,
     assert len(paths) == 1
     # corrupt it: the next fresh decode must recompute, not crash
     paths[0].write_bytes(b"garbage")
-    errs0 = runtime.DISK_CACHE_STATS["decode_errors"]
+    errs0 = diskcache.DISK_CACHE_STATS["decode_errors"]
     fn2 = fresh_fn(K.saxpy, "saxpy")
     prog = interp._decode_batched(fn2, 32, False, 1, grid_mode=True)
-    assert runtime.DISK_CACHE_STATS["decode_errors"] > errs0
+    assert diskcache.DISK_CACHE_STATS["decode_errors"] > errs0
     assert not paths[0].exists() or \
         paths[0].read_bytes() != b"garbage"
     assert prog.private_stores      # recomputed facts, not garbage
     # different kernel content -> different key (no false sharing)
     fn3 = fresh_fn(K.loop_break_continue, "loop_break_continue")
-    k_a = runtime._decode_plan_key(fn2)
-    k_b = runtime._decode_plan_key(fn3)
+    k_a = diskcache.plan_key(fn2)
+    k_b = diskcache.plan_key(fn3)
     assert k_a != k_b
     # ... and an in-place IR mutation changes the key too
-    v0 = runtime._decode_plan_key(fn2)
+    v0 = diskcache.plan_key(fn2)
     blk = fn2.entry
     from repro.core.vir import Const
     blk.insert(0, Instr(Op.ADD, [Const(Ty.I32, 1), Const(Ty.I32, 2)],
                         Reg(Ty.I32, "dead")))
-    assert runtime._decode_plan_key(fn2) != v0
+    assert diskcache.plan_key(fn2) != v0
 
 
 def test_runtime_compile_cache():
@@ -332,20 +332,20 @@ def test_compile_cache_crash_mid_write_leaves_no_truncated_entry(
     monkeypatch.setenv("VOLT_DISK_CACHE", "1")
     runtime.clear_compile_cache()
     h = BENCHES["vecadd"].handle
-    errs0 = runtime.DISK_CACHE_STATS["errors"]
+    errs0 = diskcache.DISK_CACHE_STATS["errors"]
     with faults.inject("cache.commit"):
         ck = runtime.compile_kernel(h, use_cache=False)
     assert ck is not None, "cache-write failure must never fail compile"
-    assert runtime.DISK_CACHE_STATS["errors"] > errs0
+    assert diskcache.DISK_CACHE_STATS["errors"] > errs0
     assert not list(tmp_path.glob("*.vck")), \
         "crash mid-write must not commit an entry"
     # the clean retry commits, and the entry actually loads
     runtime.compile_kernel(h, use_cache=False)
     paths = list(tmp_path.glob("*.vck"))
     assert len(paths) == 1
-    hits0 = runtime.DISK_CACHE_STATS["hits"]
+    hits0 = diskcache.DISK_CACHE_STATS["hits"]
     runtime.compile_kernel(h, use_cache=False)
-    assert runtime.DISK_CACHE_STATS["hits"] > hits0
+    assert diskcache.DISK_CACHE_STATS["hits"] > hits0
     runtime.clear_compile_cache()
 
 
@@ -370,6 +370,6 @@ def test_decode_plan_crash_mid_write_leaves_no_truncated_entry(
     # clean rerun persists a loadable plan
     interp._decode_batched(fresh_fn(), 32, False, 1, grid_mode=True)
     assert list(tmp_path.glob("*.vdp"))
-    hits0 = runtime.DISK_CACHE_STATS["decode_hits"]
+    hits0 = diskcache.DISK_CACHE_STATS["decode_hits"]
     interp._decode_batched(fresh_fn(), 32, False, 1, grid_mode=True)
-    assert runtime.DISK_CACHE_STATS["decode_hits"] > hits0
+    assert diskcache.DISK_CACHE_STATS["decode_hits"] > hits0

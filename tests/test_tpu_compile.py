@@ -1,7 +1,7 @@
 """The jax rung's chunk programs compile for a TPU v5e chip.
 
-For each kernel of ``suite.USER_SIZES`` (the sizes ``chip_smoke.py``
-launches on the chip), the benchmark's Kronecker SpMV at its scale
+For each kernel of ``suite.USER_SIZES`` (user-sized launches of four
+suite kernels), the benchmark's Kronecker SpMV at its scale
 (``chipbench/configs/spmv_kron*``, its ragged loop compacted), and each
 executable tier, the chunk program that
 ``jaxgen`` traces for that launch is compiled for device 0 of a
